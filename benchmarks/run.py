@@ -39,6 +39,8 @@ def main() -> None:
                          "benchmarks.gate regression ratchet over the "
                          "BENCH_*.json histories")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for mod_name in MODULES:

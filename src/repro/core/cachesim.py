@@ -123,8 +123,7 @@ def simulate_capacity(trace: np.ndarray, capacity_mb: float, *,
 def simulate_ladder(traces: np.ndarray,
                     capacities_mb: Optional[Sequence[float]] = None, *,
                     scale: int = 1, ways: int = 16, sets_tile: int = 2048,
-                    use_kernel: bool = True,
-                    interpret: Optional[bool] = None) -> np.ndarray:
+                    use_kernel: bool = True) -> np.ndarray:
     """Batched trace-driven sweep: (workloads x capacity ladder) in one call.
 
     ``traces`` is (W, T) line ids (a single (T,) trace is promoted);
@@ -148,7 +147,7 @@ def simulate_ladder(traces: np.ndarray,
         from repro.kernels.ops import cache_sim_ladder
         counts = cache_sim_ladder(jnp.asarray(traces, jnp.int32),
                                   num_sets=ladder, ways=ways,
-                                  sets_tile=sets_tile, interpret=interpret)
+                                  sets_tile=sets_tile)
         return np.asarray(counts, np.int64)
     from repro.kernels.ref import cache_sim_ladder_numpy
     return cache_sim_ladder_numpy(traces, ladder, ways=ways)

@@ -27,8 +27,6 @@ DRAM_IDLE_POWER_MW = 0.0             # background power folded into GPU board
 MISS_ALPHA = 0.186
 
 # --- TPU v5e-class (crosslayer mode) ---------------------------------------
-TPU_PEAK_FLOPS = 197e12
-TPU_HBM_BW = 819e9
 TPU_HBM_ENERGY_NJ_PER_128B = 128 * 8 * 0.004   # ~4 pJ/bit HBM2e-class
 TPU_SRAM_TIER_MB = 128               # modeled on-chip last-level SRAM tier
 TPU_CLOCK_GHZ = 0.94

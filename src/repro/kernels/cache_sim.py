@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 EMPTY = -1  # empty-way tag sentinel
+TRACE_CHUNK = 16384  # ladder trace ids per SMEM block (SMEM holds 1 MiB)
 
 
 def _cachesim_kernel(setid_ref, tag_ref, out_ref, tags_scr, age_scr,
@@ -108,28 +109,36 @@ def cache_sim(set_ids, tags, *, num_sets: int, ways: int,
 
 
 def _ladder_kernel(ns_ref, base_ref, trace_ref, out_ref, tags_scr, age_scr,
-                   *, sets_tile: int, ways: int, trace_len: int):
-    ns = ns_ref[0]                               # this tile's rung set count
-    s0 = base_ref[0]                             # first set owned by the tile
-    tags_scr[...] = jnp.full(tags_scr.shape, EMPTY, tags_scr.dtype)
-    age_scr[...] = jnp.zeros_like(age_scr)
+                   cnt_scr, *, sets_tile: int, ways: int, chunk: int,
+                   trace_len: int):
+    g = pl.program_id(1)
+    c = pl.program_id(2)
+    ns = ns_ref[g]                               # this tile's rung set count
+    s0 = base_ref[g]                             # first set owned by the tile
 
-    trace = trace_ref[0, :]
-    set_ids = trace % ns
-    tags_in = trace // ns
+    @pl.when(c == 0)
+    def _init():
+        tags_scr[...] = jnp.full(tags_scr.shape, EMPTY, tags_scr.dtype)
+        age_scr[...] = jnp.zeros_like(age_scr)
+        cnt_scr[0] = 0
+        cnt_scr[1] = 0
+
     way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, ways), 1)
 
     def step(t, carry):
         hits, misses = carry
-        sid = set_ids[t] - s0                    # local set row
-        tag = tags_in[t]
+        line = trace_ref[0, t]
+        sid = line % ns - s0                     # local set row
+        tag = line // ns
         in_tile = (sid >= 0) & (sid < sets_tile)
         row = jnp.where(in_tile, sid, 0)
         row_tags = tags_scr[pl.ds(row, 1), :]    # (1, ways)
         row_ages = age_scr[pl.ds(row, 1), :]
         hit_way = jnp.min(jnp.where(row_tags == tag, way_iota, ways))
         hit = hit_way < ways
-        victim = jnp.argmax(row_ages)            # LRU way: max age wins
+        # LRU way: the first way of max age (Mosaic argmaxes f32 only)
+        victim = jnp.min(jnp.where(row_ages == jnp.max(row_ages), way_iota,
+                                   ways))
         way = jnp.where(hit, hit_way, victim)
         touched = way_iota == way
         # touched way -> age 0; rest of the row ages by one
@@ -141,10 +150,16 @@ def _ladder_kernel(ns_ref, base_ref, trace_ref, out_ref, tags_scr, age_scr,
         return (hits + jnp.where(in_tile & hit, 1, 0),
                 misses + jnp.where(in_tile & ~hit, 1, 0))
 
-    h, m = jax.lax.fori_loop(0, trace_len, step,
-                             (jnp.int32(0), jnp.int32(0)))
-    out_ref[0, 0, 0] = h
-    out_ref[0, 0, 1] = m
+    # the last chunk stops at the trace's end (its padding is never read)
+    n = jnp.minimum(chunk, trace_len - c * chunk)
+    h, m = jax.lax.fori_loop(0, n, step, (cnt_scr[0], cnt_scr[1]))
+    cnt_scr[0] = h
+    cnt_scr[1] = m
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _emit():
+        out_ref[0, 0] = h
+        out_ref[0, 1] = m
 
 
 def ladder_tiles(num_sets_ladder, sets_tile: int):
@@ -168,34 +183,51 @@ def ladder_tiles(num_sets_ladder, sets_tile: int):
 
 
 def cache_sim_ladder(traces, num_sets_ladder, *, ways: int,
-                     sets_tile: int = 2048, interpret: bool = False):
+                     sets_tile: int = 2048, chunk: int = TRACE_CHUNK,
+                     interpret: bool = False):
     """Simulate every (trace, ladder rung) pair in one Pallas launch.
 
     ``traces`` is (W, T) int32 line ids; ``num_sets_ladder`` a static tuple
     of per-rung set counts. Returns (W, L, 2) int32 [hits, misses].
+
+    Grid (W, tiles, trace chunks), chunks innermost: each access's line id
+    is a scalar read from SMEM, which holds one double-buffered chunk of
+    the trace at a time, so the trace length is unbounded by SMEM.
     """
     traces = jnp.asarray(traces, jnp.int32)
     W, T = traces.shape
     tile, ns_of, base_of, rung_of = ladder_tiles(num_sets_ladder, sets_tile)
     G = len(ns_of)
+    chunk = max(1, min(int(chunk), T))
+    nc = -(-T // chunk)
+    traces = jnp.pad(traces, ((0, 0), (0, nc * chunk - T)))
     kernel = functools.partial(_ladder_kernel, sets_tile=tile, ways=ways,
-                               trace_len=T)
-    counts = pl.pallas_call(
-        kernel,
-        grid=(W, G),
-        in_specs=[
-            pl.BlockSpec((1,), lambda w, g: (g,)),
-            pl.BlockSpec((1,), lambda w, g: (g,)),
-            pl.BlockSpec((1, T), lambda w, g: (w, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 2), lambda w, g: (w, g, 0)),
-        out_shape=jax.ShapeDtypeStruct((W, G, 2), jnp.int32),
+                               chunk=chunk, trace_len=T)
+    smem = pltpu.MemorySpace.SMEM
+    # trace rows and outputs carry a unit axis so that their blocks' two
+    # minor dims equal the array's, as Mosaic requires
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                   # per-tile set count, base
+        grid=(W, G, nc),
+        in_specs=[pl.BlockSpec((None, 1, chunk),
+                               lambda w, g, c, ns, base: (w, 0, c),
+                               memory_space=smem)],
+        out_specs=pl.BlockSpec((None, None, 1, 2),
+                               lambda w, g, c, ns, base: (w, g, 0, 0),
+                               memory_space=smem),
         scratch_shapes=[
             pltpu.VMEM((tile, ways), jnp.int32),
             pltpu.VMEM((tile, ways), jnp.int32),
+            pltpu.SMEM((2,), jnp.int32),         # running hits, misses
         ],
+    )
+    counts = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((W, G, 1, 2), jnp.int32),
         interpret=interpret,
-    )(jnp.asarray(ns_of, jnp.int32), jnp.asarray(base_of, jnp.int32), traces)
+    )(jnp.asarray(ns_of, jnp.int32), jnp.asarray(base_of, jnp.int32),
+      traces[:, None, :])[:, :, 0]
     # tile -> rung reduction (pure bookkeeping; rung ids are static)
     seg = jnp.asarray(rung_of, jnp.int32)
     per_rung = jax.ops.segment_sum(counts.transpose(1, 0, 2), seg,

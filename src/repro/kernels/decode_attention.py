@@ -48,6 +48,14 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -2.0e38
 
 
+# Scoped VMEM the kernel declares to Mosaic (v5e has 128 MiB per core;
+# the compiler's default scope is 16 MiB), and the share of it the KV
+# blocks may plan for: the rest covers what the row estimate below
+# leaves out (q, scratch, score tensors, the compiler's own relayouts).
+VMEM_LIMIT_BYTES = 32 << 20
+_KV_BLOCK_BUDGET = VMEM_LIMIT_BYTES // 2
+
+
 def decode_block_size(max_len: int, bk: int) -> int:
     """Largest KV block <= ``bk`` that divides ``max_len`` (the kernel
     tiles the cache exactly; same contract as cachesim's divisor tile)."""
@@ -55,6 +63,27 @@ def decode_block_size(max_len: int, bk: int) -> int:
         if max_len % tile == 0:
             return tile
     return 1
+
+
+def kv_block_rows(max_len: int, kv_heads: int, head_dim: int, dtype, *,
+                  fused: bool, bk: int = 128) -> int:
+    """KV block rows for the decode kernel, sized from the VMEM budget.
+
+    One cache row costs, in VMEM: its k and v blocks (plus the aliased
+    k/v output blocks when ``fused``), each double-buffered in the cache
+    dtype with sublanes padded to the dtype's packing and lanes to 128;
+    and four f32 copies of (K, hd) — the upcast k/v blocks and the
+    relayouts the batched dots make of them.  The block is the largest
+    divisor of ``max_len`` that is at most ``bk`` and fits the budget.
+    """
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * 4 // itemsize
+    lanes = -(-head_dim // 128) * 128
+    k_pad = -(-kv_heads // sublanes) * sublanes
+    streams = 4 if fused else 2
+    row = (k_pad * lanes * itemsize * 2 * streams
+           + kv_heads * lanes * 4 * 4)
+    return decode_block_size(max_len, max(1, min(bk, _KV_BLOCK_BUDGET // row)))
 
 
 def _block_bounds(pos_b, win, bk):
@@ -140,7 +169,7 @@ def _call(q, k, v, pos, window, new_k, new_v, *, logit_cap, bk, fused,
     if H % K:
         raise ValueError(f"q heads {H} not divisible by kv heads {K}")
     G = H // K
-    bk = decode_block_size(L, bk)
+    bk = kv_block_rows(L, K, hd, k.dtype, fused=fused, bk=bk)
     nk = L // bk
 
     pos = jnp.asarray(pos, jnp.int32)
@@ -196,6 +225,8 @@ def _call(q, k, v, pos, window, new_k, new_v, *, logit_cap, bk, fused,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(pos, win, *operands)
     return tuple(out) if fused else out[0]
@@ -205,7 +236,8 @@ def decode_attention(q, k, v, pos, window=0, *, logit_cap: float = 0.0,
                      bk: int = 128, interpret: bool = False):
     """Blocked decode attention; the cache already holds the new KV row.
 
-    q (B,H,hd); k/v (B,L,K,hd); pos (B,) int32 -> o (B,H,hd)."""
+    q (B,H,hd); k/v (B,L,K,hd); pos (B,) int32 -> o (B,H,hd).  ``bk``
+    caps the KV block; ``kv_block_rows`` may shrink it to fit VMEM."""
     return _call(q, k, v, pos, window, None, None, logit_cap=logit_cap,
                  bk=bk, fused=False, interpret=interpret)
 

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run with interpret=True, which executes the
-kernel body in Python for correctness validation; on TPU they compile to
-Mosaic. ``interpret=None`` auto-detects.
+The backend picks the mode, and no caller can override it: on the CPU the
+kernels run in interpret mode, which executes the kernel body in Python
+for correctness tests; on a TPU they always compile to Mosaic.
 """
 from __future__ import annotations
 
@@ -20,35 +20,32 @@ from repro.kernels import sampling as _sm
 from repro.kernels import ssd_scan as _ssd
 
 
-def _interpret(flag):
-    if flag is not None:
-        return flag
+def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "logit_cap", "bq",
-                                   "bk", "interpret"))
+                                   "bk"))
 def flash_attention(q, k, v, *, causal=True, window=0, logit_cap=0.0,
-                    bq=128, bk=128, interpret=None):
+                    bq=128, bk=128):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                logit_cap=logit_cap, bq=bq, bk=bk,
-                               interpret=_interpret(interpret))
+                               interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("logit_cap", "bk", "interpret"))
-def decode_attention(q, k, v, pos, window, *, logit_cap=0.0, bk=128,
-                     interpret=None):
+@partial(jax.jit, static_argnames=("logit_cap", "bk"))
+def decode_attention(q, k, v, pos, window, *, logit_cap=0.0, bk=128):
     """Blocked serve-decode attention (cache already holds the new row).
 
     q (B,H,hd); k/v (B,L,K,hd); pos (B,) i32; window i32 scalar (may be
     traced; <= 0 = global) -> (B,H,hd)."""
     return _da.decode_attention(q, k, v, pos, window, logit_cap=logit_cap,
-                                bk=bk, interpret=_interpret(interpret))
+                                bk=bk, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("logit_cap", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("logit_cap", "bk"))
 def decode_attention_fused(q, k, v, new_k, new_v, pos, window, *,
-                           logit_cap=0.0, bk=128, interpret=None):
+                           logit_cap=0.0, bk=128):
     """Fused per-row KV scatter + blocked decode attention.
 
     Writes new_k/new_v (B,K,hd) at each row's own pos[b] inside the
@@ -56,24 +53,24 @@ def decode_attention_fused(q, k, v, new_k, new_v, pos, window, *,
     returns (o, k_cache, v_cache)."""
     return _da.decode_attention_fused(
         q, k, v, new_k, new_v, pos, window, logit_cap=logit_cap, bk=bk,
-        interpret=_interpret(interpret))
+        interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("logit_cap", "interpret"))
+@partial(jax.jit, static_argnames=("logit_cap",))
 def paged_decode_attention(q, k, v, page_table, pos, window, *,
-                           logit_cap=0.0, interpret=None):
+                           logit_cap=0.0):
     """Paged serve-decode attention (pool already holds the new row).
 
     q (B,H,hd); k/v pools (P,ps,K,hd); page_table (B,nb) i32; pos (B,)
     i32; window i32 scalar (may be traced; <= 0 = global) -> (B,H,hd)."""
     return _pa.paged_decode_attention(
         q, k, v, page_table, pos, window, logit_cap=logit_cap,
-        interpret=_interpret(interpret))
+        interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("logit_cap", "interpret"))
+@partial(jax.jit, static_argnames=("logit_cap",))
 def paged_decode_attention_fused(q, k, v, new_k, new_v, page_table, pos,
-                                 window, *, logit_cap=0.0, interpret=None):
+                                 window, *, logit_cap=0.0):
     """Fused through-the-page-table KV scatter + paged decode attention.
 
     Writes new_k/new_v (B,K,hd) into each row's boundary page at
@@ -81,45 +78,41 @@ def paged_decode_attention_fused(q, k, v, new_k, new_v, page_table, pos,
     (o, k_pool, v_pool)."""
     return _pa.paged_decode_attention_fused(
         q, k, v, new_k, new_v, page_table, pos, window,
-        logit_cap=logit_cap, interpret=_interpret(interpret))
+        logit_cap=logit_cap, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("bv", "interpret"))
-def fused_sample(logits, temps, key, *, bv=512, interpret=None):
+@partial(jax.jit, static_argnames=("bv",))
+def fused_sample(logits, temps, key, *, bv=2048):
     """One-launch greedy/temperature next-token sample.
 
     logits (B,V); temps (B,) (<= 0 greedy, bitwise == argmax; > 0
     in-kernel Gumbel-max); key (2,) uint32 -> (B,) int32."""
     return _sm.fused_sample(logits, temps, key, bv=bv,
-                            interpret=_interpret(interpret))
+                            interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, dtA, Bmat, Cmat, *, chunk=128, interpret=None):
+@partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan(x, dt, dtA, Bmat, Cmat, *, chunk=128):
     return _ssd.ssd_scan(x, dt, dtA, Bmat, Cmat, chunk=chunk,
-                         interpret=_interpret(interpret))
+                         interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("block", "width_tile", "interpret"))
-def rglru_scan(a, b, *, block=256, width_tile=512, interpret=None):
+@partial(jax.jit, static_argnames=("block", "width_tile"))
+def rglru_scan(a, b, *, block=256, width_tile=512):
     return _rg.rglru_scan_kernel(a, b, block=block, width_tile=width_tile,
-                                 interpret=_interpret(interpret))
+                                 interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("num_sets", "ways", "sets_tile",
-                                   "interpret"))
-def cache_sim(set_ids, tags, *, num_sets, ways, sets_tile=128,
-              interpret=None):
+@partial(jax.jit, static_argnames=("num_sets", "ways", "sets_tile"))
+def cache_sim(set_ids, tags, *, num_sets, ways, sets_tile=128):
     return _cs.cache_sim(set_ids, tags, num_sets=num_sets, ways=ways,
-                         sets_tile=sets_tile, interpret=_interpret(interpret))
+                         sets_tile=sets_tile, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("num_sets", "ways", "sets_tile",
-                                   "interpret"))
-def cache_sim_ladder(traces, *, num_sets, ways, sets_tile=2048,
-                     interpret=None):
+@partial(jax.jit, static_argnames=("num_sets", "ways", "sets_tile"))
+def cache_sim_ladder(traces, *, num_sets, ways, sets_tile=2048):
     """Batched ladder engine; ``num_sets`` is a static tuple of rung set
     counts. Returns (W, L, 2) int32 [hits, misses]."""
     return _cs.cache_sim_ladder(traces, num_sets, ways=ways,
                                 sets_tile=sets_tile,
-                                interpret=_interpret(interpret))
+                                interpret=_interpret())

@@ -5,8 +5,8 @@ The engine's two-step sampler (``jnp.argmax`` + ``jax.random.categorical``
 tensor through three separate XLA ops per tick.  This kernel folds the
 whole per-row sample into one launch blocked over the vocab:
 
-  grid (B, nv), j innermost (sequential, carries scratch);
-  per block: running (max, first-argmax) reduction in VMEM scratch.
+  grid (row blocks, nv), j innermost (sequential, carries scratch);
+  per block: running per-row (max, first-argmax) in VMEM scratch.
 
 Greedy rows (``temps[b] <= 0``) reduce the raw logits and are
 *bitwise-equal* to ``jnp.argmax`` (strictly-greater cross-block updates
@@ -31,7 +31,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import NEG_INF, decode_block_size
+from repro.kernels.decode_attention import NEG_INF
+
+_ROW_BLOCK = 8     # f32 sublanes per vreg
+_LANES = 128
 
 
 def _shr(h, n):
@@ -49,85 +52,96 @@ def _fmix(h):
 
 
 def _sample_kernel(seed_ref, temps_ref, logits_ref, o_ref, m_scr, i_scr, *,
-                   bv: int):
-    b = pl.program_id(0)
+                   bb: int, bv: int, vocab: int):
+    i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[0, 0] = NEG_INF
-        i_scr[0, 0] = 0
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        i_scr[...] = jnp.zeros_like(i_scr)
 
-    x = logits_ref[...].astype(jnp.float32)               # (1, bv)
-    t = temps_ref[0, 0]
+    x = logits_ref[...].astype(jnp.float32)               # (bb, bv)
+    t = temps_ref[...]                                    # (bb, 1)
 
     # Gumbel-max: argmax(logits/t + g) ~ Categorical(softmax(logits/t)).
     # Counter = the element's flat (row, vocab) index; each key word is
     # folded in through a murmur3 finalizer round.
-    col = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 1)
-    vocab = jnp.uint32(pl.num_programs(1) * bv)
-    ctr = (b.astype(jnp.uint32) * vocab
-           + j.astype(jnp.uint32) * jnp.uint32(bv) + col)
-    k0 = jax.lax.bitcast_convert_type(seed_ref[0], jnp.uint32)
-    k1 = jax.lax.bitcast_convert_type(seed_ref[1], jnp.uint32)
+    row = i * bb + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    col = j * bv + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    ctr = (row.astype(jnp.uint32) * jnp.uint32(vocab)
+           + col.astype(jnp.uint32))
+    # key words are bitcast as vectors (Mosaic bitcasts no scalars)
+    k0, k1 = (jax.lax.bitcast_convert_type(
+        jnp.full(x.shape, seed_ref[w], jnp.int32), jnp.uint32) for w in (0, 1))
     bits = _fmix(_fmix(ctr ^ k0) ^ k1)
-    frac = _shr(bits, 9).astype(jnp.float32)
+    # 23 random bits fit int32 exactly (Mosaic has no u32 -> f32 cast)
+    frac = jax.lax.bitcast_convert_type(_shr(bits, 9), jnp.int32).astype(
+        jnp.float32)
     u = frac * (2.0 ** -23) + (2.0 ** -24)                # u in (0, 1)
     g = -jnp.log(-jnp.log(u))
     x = jnp.where(t > 0.0, x / jnp.maximum(t, 1e-6) + g, x)
+    # the ragged last vocab block reads padding: it can never win
+    x = jnp.where(col < vocab, x, -jnp.inf)
 
-    vmax = jnp.max(x)
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    vmax = jnp.max(x, axis=1, keepdims=True)              # (bb, 1)
     # first index attaining the block max (jnp.argmax tie-break)
-    loc = jnp.min(jnp.where(x == vmax, col, jnp.int32(2 ** 31 - 1)))
-    cand = j * bv + loc
-    better = vmax > m_scr[0, 0]   # strict: earlier blocks win ties
-    i_scr[0, 0] = jnp.where(better, cand, i_scr[0, 0])
-    m_scr[0, 0] = jnp.where(better, vmax, m_scr[0, 0])
+    loc = jnp.min(jnp.where(x == vmax, col, jnp.int32(2 ** 31 - 1)),
+                  axis=1, keepdims=True)
+    better = vmax > m_scr[...]    # strict: earlier blocks win ties
+    i_scr[...] = jnp.where(better, loc, i_scr[...])
+    m_scr[...] = jnp.where(better, vmax, m_scr[...])
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
-        o_ref[0, 0] = i_scr[0, 0]
+        o_ref[...] = i_scr[...]
 
 
-def fused_sample(logits, temps, key, *, bv: int = 512,
+def fused_sample(logits, temps, key, *, bv: int = 2048,
                  interpret: bool = False):
     """One-launch greedy/temperature sample of the next token per row.
 
     logits (B, V); temps (B,) — <= 0 greedy, > 0 Gumbel-max at that
     temperature; key (2,) uint32 PRNG key data -> tokens (B,) int32.
+
+    Rows tile in blocks of 8 and the vocabulary in lane-aligned blocks of
+    ``bv`` (rounded to a multiple of 128): vocabularies such as 32064 =
+    2^6 * 501 have no 128-aligned divisor, so both axes are padded up to
+    whole blocks and the kernel masks the padding out.
     """
     B, V = logits.shape
-    bv = decode_block_size(V, bv)
-    nv = V // bv
-
+    bb = _ROW_BLOCK
+    bv = min(-(-int(bv) // _LANES), -(-V // _LANES)) * _LANES
+    nb, nv = -(-B // bb), -(-V // bv)
+    logits = jnp.pad(logits, ((0, nb * bb - B), (0, nv * bv - V)))
+    temps2 = jnp.pad(jnp.asarray(temps, jnp.float32),
+                     (0, nb * bb - B)).reshape(nb * bb, 1)
     seed = jax.lax.bitcast_convert_type(
         jnp.asarray(key, jnp.uint32), jnp.int32)
-    temps2 = jnp.asarray(temps, jnp.float32).reshape(B, 1)
 
-    def row_map(b, j, seed_ref):
-        return (b, 0)
+    def row_map(i, j, seed_ref):
+        return (i, 0)
 
-    def blk_map(b, j, seed_ref):
-        return (b, j)
+    def blk_map(i, j, seed_ref):
+        return (i, j)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, nv),
+        grid=(nb, nv),
         in_specs=[
-            pl.BlockSpec((1, 1), row_map),
-            pl.BlockSpec((1, bv), blk_map),
+            pl.BlockSpec((bb, 1), row_map),
+            pl.BlockSpec((bb, bv), blk_map),
         ],
-        out_specs=[pl.BlockSpec((1, 1), row_map)],
+        out_specs=pl.BlockSpec((bb, 1), row_map),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),  # running max
-            pltpu.VMEM((1, 1), jnp.int32),    # its first index
+            pltpu.VMEM((bb, 1), jnp.float32),  # running max per row
+            pltpu.VMEM((bb, 1), jnp.int32),    # its first index
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_sample_kernel, bv=bv),
+        functools.partial(_sample_kernel, bb=bb, bv=bv, vocab=V),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((nb * bb, 1), jnp.int32),
         interpret=interpret,
     )(seed, temps2, logits)
-    return out[0][:, 0]
+    return out[:B, 0]

@@ -13,19 +13,28 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def _make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place
+    parameters and let the compiler (GSPMD) propagate the rest.  JAX's
+    default is Explicit axes, under which every traced op must agree on
+    sharding types (Pallas interpret mode, for one, does not)."""
     import jax
+    from jax.sharding import AxisType
 
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_mesh_for(num_devices: int, *, model_parallelism: int = 16,
-                  pods: int = 1):
-    """Largest (pod, data, model) mesh that fits ``num_devices`` devices."""
-    import jax
-
+                  pods: int = 1, devices: Optional[Sequence] = None):
+    """Largest (pod, data, model) mesh that fits ``num_devices`` devices,
+    built from ``devices`` (default: every device of the backend)."""
     model = model_parallelism
     while model > 1 and num_devices % model:
         model //= 2
@@ -35,8 +44,9 @@ def make_mesh_for(num_devices: int, *, model_parallelism: int = 16,
             f"cannot build mesh: {num_devices} devices, model={model}, "
             f"pods={pods}")
     if pods > 1:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pods, data, model), ("pod", "data", "model"),
+                          devices)
+    return _make_mesh((data, model), ("data", "model"), devices)
 
 
 def mesh_axis_sizes(mesh) -> dict:
@@ -44,12 +54,8 @@ def mesh_axis_sizes(mesh) -> dict:
 
 
 def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available (jax >= 0.5), else the Mesh's
-    own context manager — the launchers' single mesh-scoping entry point so
-    they run on every jax this repo supports."""
+    """``jax.set_mesh(mesh)``: the launchers' single mesh-scoping entry
+    point."""
     import jax
 
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    return jax.set_mesh(mesh)

@@ -3,9 +3,11 @@
 Terms (per DESIGN.md §8 — cost_analysis on this JAX build reports PER-DEVICE
 flops/bytes, verified empirically):
 
-    compute_term    = flops_per_device / PEAK_FLOPS
-    memory_term     = bytes_per_device / HBM_BW
-    collective_term = link_bytes_per_device / ICI_BW
+    compute_term    = flops_per_device / peak FLOP/s
+    memory_term     = bytes_per_device / HBM bytes/s
+    collective_term = link_bytes_per_device / ICI bytes/s per link
+
+with the peaks of the chip the program was compiled for (``PEAKS``).
 
 collective bytes are parsed from the optimized HLO text with ring-model
 factors: all-gather / reduce-scatter x(n-1)/n, all-reduce x2(n-1)/n,
@@ -19,10 +21,44 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-# TPU v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12        # bf16 FLOP/s
-HBM_BW = 819e9             # bytes/s
-ICI_BW = 50e9              # bytes/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks that price a roofline."""
+    flops: float       # bf16 FLOP/s
+    hbm_bw: float      # HBM bytes/s
+    ici_bw: float      # interconnect bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: 197 TFLOP/s bf16, 16 GB
+# of HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 links (Google
+# Cloud documentation, "TPU v5e").  A chip that is not here is an error.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+# The chip a CPU compile (dry runs, tests) is priced as.
+MODELED_KIND = "TPU v5 lite"
+
+
+class UnknownChipError(KeyError):
+    """A TPU whose ``device_kind`` has no entry in ``PEAKS``."""
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownChipError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add it to launch/roofline.PEAKS "
+            "with its source") from None
+
+
+def priced_kind(device) -> str:
+    """The ``PEAKS`` key that prices a program compiled for ``device``:
+    a TPU's own kind, or ``MODELED_KIND`` for the CPU backend."""
+    return MODELED_KIND if device.platform == "cpu" else device.device_kind
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -143,22 +179,28 @@ class Roofline:
     collective_counts: Dict[str, int]
     temp_bytes: float
     arg_bytes: float
+    device_kind: str         # PEAKS key of the chip the program targets
     xla_flops: float = 0.0   # raw cost_analysis (while bodies counted once)
     xla_bytes: float = 0.0
     bytes_by_scope: Dict[str, float] = None
     flops_by_scope: Dict[str, float] = None
 
     @property
+    def peaks(self) -> ChipPeaks:
+        """Raises ``UnknownChipError`` for a chip missing from PEAKS."""
+        return chip_peaks(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -174,7 +216,7 @@ class Roofline:
         """MODEL_FLOPS fraction of the roofline bound (MFU-like)."""
         if self.bound_s <= 0:
             return 0.0
-        return model_flops_per_device / PEAK_FLOPS / self.bound_s
+        return model_flops_per_device / self.peaks.flops / self.bound_s
 
     def to_dict(self) -> dict:
         return {
@@ -185,6 +227,7 @@ class Roofline:
             "collective_counts": self.collective_counts,
             "temp_bytes": self.temp_bytes,
             "arg_bytes": self.arg_bytes,
+            "device_kind": self.device_kind,
             "xla_flops": self.xla_flops,
             "xla_bytes": self.xla_bytes,
             "bytes_by_scope": self.bytes_by_scope,
@@ -206,6 +249,11 @@ def analyze(compiled) -> Roofline:
     """
     from repro.launch.hlo_analysis import analyze_hlo
 
+    import jax
+
+    shardings = jax.tree.leaves(compiled.input_shardings)
+    device = (min(shardings[0].device_set, key=lambda d: d.id) if shardings
+              else jax.devices()[0])
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0]
@@ -227,6 +275,7 @@ def analyze(compiled) -> Roofline:
         collective_counts=stats.collective_counts,
         temp_bytes=temp,
         arg_bytes=arg,
+        device_kind=priced_kind(device),
         xla_flops=xla_flops,
         xla_bytes=xla_bytes,
         bytes_by_scope=stats.bytes_by_scope,

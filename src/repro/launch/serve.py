@@ -31,7 +31,14 @@ arrival-driven request an absolute deadline and ``--max-queue-depth``
 caps the admission queue (excess submissions shed).  Every run prints a
 terminal-state histogram next to the paged-stats line, and ``--strict``
 (default on) exits non-zero if any request ended FAILED or never
-reached a terminal state — the CI smokes lean on that exit code.
+reached a terminal state, or if the watchdog degraded a failing
+compiled decode window to the eager path — the CI smokes lean on that
+exit code.
+
+The parameters are sharded over a (data, model) mesh of every device the
+backend exposes (on a four-chip host, model=4).  ``parse_args``,
+``build_engine``, ``make_requests``, ``serve`` and ``report`` are the
+steps of ``main``; ``chip_smoke.py`` calls them in-process.
 """
 import argparse
 import collections
@@ -40,6 +47,7 @@ import time
 import jax
 
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_context
 from repro.models import build_model
 from repro.serve import (FAILED, Engine, PagedEngine, ShedPolicy, Tracer,
@@ -62,8 +70,9 @@ def _print_latency(summary: dict) -> None:
 
 def _terminal_report(eng, reqs, strict: bool) -> None:
     """Terminal-state histogram + strict-mode exit code: FAILED or
-    non-terminal requests are a launcher failure, shed/timed-out are
-    legitimate admission-control outcomes (reported, not fatal)."""
+    non-terminal requests, and a decode window the watchdog degraded to
+    the eager path, are a launcher failure; shed/timed-out are legitimate
+    admission-control outcomes (reported, not fatal)."""
     hist = collections.Counter(r.state for r in reqs)
     rs = eng.resilience_stats()
     extras = {k: v for k, v in rs.items()
@@ -78,6 +87,11 @@ def _terminal_report(eng, reqs, strict: bool) -> None:
             f"strict mode: {len(stuck)} non-terminal {stuck[:8]} / "
             f"{len(failed)} FAILED {failed[:8]} requests "
             f"(states: {dict(hist)})")
+    if strict and (rs["degraded"] or rs["window_fallbacks"]):
+        raise SystemExit(
+            f"strict mode: the compiled decode window failed and "
+            f"{rs['window_fallbacks']} window(s) ran on the eager path "
+            f"after {rs['window_retries']} retries")
 
 
 def _list_configs() -> None:
@@ -95,7 +109,7 @@ def _list_configs() -> None:
         print(f"{arch:<22} {cfg.family:<8} {', '.join(engines)}")
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
     ap.add_argument("--list-configs", action="store_true",
@@ -104,6 +118,14 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--prompt-lens", type=int, nargs=2, default=None,
+                    metavar=("LO", "HI"),
+                    help="prompt length range of the mixed and arrival "
+                         "workloads (default 2 .. max_len/4)")
+    ap.add_argument("--max-new", type=int, nargs=2, default=None,
+                    metavar=("LO", "HI"),
+                    help="new-token budget range of every workload "
+                         "(default 2 .. max_len/8; arrivals 1 .. max_len/8)")
     ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="smoke-sized config (--no-reduced for full size)")
@@ -161,13 +183,17 @@ def main():
     ap.add_argument("--strict", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="exit non-zero if any request ends FAILED or "
-                         "non-terminal (--no-strict to just report)")
-    args = ap.parse_args()
-    if args.list_configs:
-        _list_configs()
-        return
+                         "non-terminal, or a decode window degraded to "
+                         "the eager path (--no-strict to just report)")
+    return ap.parse_args(argv)
 
-    mesh = remesh(jax.device_count())
+
+def build_engine(args, devices=None):
+    """(mesh, engine) the arguments describe.  Parameters are drawn
+    directly into their shardings over a (data, model) mesh of
+    ``devices`` (default: every device of the backend)."""
+    devices = list(jax.devices() if devices is None else devices)
+    mesh = remesh(len(devices), devices)
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -176,9 +202,9 @@ def main():
 
     tracer = Tracer(name=f"serve-{args.arch}") if args.trace_out else None
     with mesh_context(mesh):
-        params = model.init(jax.random.PRNGKey(0))
-        p_sh = tree_shardings(model.param_axes(), params, mesh, rules)
-        params = jax.tree.map(jax.device_put, params, p_sh)
+        p_sh = tree_shardings(model.param_axes(), model.abstract_params(),
+                              mesh, rules)
+        params = model.init(jax.random.PRNGKey(0), p_sh)
         paged = args.attn_impl in ("paged", "pallas_paged")
         policy = ShedPolicy(max_queue_depth=args.max_queue_depth)
         if paged:
@@ -200,47 +226,60 @@ def main():
                          sample_impl=args.sample_impl,
                          attn_impl=args.attn_impl, tracer=tracer,
                          shed_policy=policy)
-        temp_every = 2 if args.temperature > 0 else 0
-        t0 = time.time()
-        if args.shared_prefix:
-            # template length deliberately off the page grid so boundary
-            # CoW copies exercise on every admission wave
-            tlen = max(args.page_size + args.page_size // 2,
-                       args.max_len // 2 - args.page_size // 2)
-            reqs = shared_prefix_requests(
-                args.requests, seed=args.seed, vocab=cfg.vocab_size,
-                template_len=min(tlen, args.max_len - 10),
-                suffix_lens=(2, 8),
-                max_new=(2, max(2, args.max_len // 8)),
-                temperature=args.temperature, temperature_every=temp_every)
-            outputs = run_staggered(eng, staggered_groups(reqs, args.slots))
-        elif args.arrival_rate > 0:
-            reqs = poisson_requests(
-                args.requests, seed=args.seed, vocab=cfg.vocab_size,
-                arrival_rate=args.arrival_rate, burst_amp=args.burst_amp,
-                burst_period=args.burst_period,
-                prompt_bounds=(2, max(2, args.max_len // 4)),
-                new_bounds=(1, max(2, args.max_len // 8)),
-                temperature=args.temperature,
-                temperature_every=temp_every,
-                deadline_ticks=args.deadline_ticks)
-            outputs = run_arrivals(eng, reqs)
-        else:
-            reqs = mixed_requests(
-                args.requests, seed=args.seed, vocab=cfg.vocab_size,
-                prompt_lens=(2, max(2, args.max_len // 4)),
-                max_new=(2, max(2, args.max_len // 8)),
-                temperature=args.temperature,
-                temperature_every=temp_every)
-            outputs = run_staggered(eng, staggered_groups(reqs, args.slots))
-        jax.block_until_ready(eng.cache)   # timings are blocking-clock
-        dt = time.time() - t0
+    return mesh, eng
+
+
+def make_requests(args, vocab: int) -> list:
+    """The workload the arguments select: shared-prefix templates,
+    Poisson arrivals, or staggered mixed-length requests."""
+    temp_every = 2 if args.temperature > 0 else 0
+    hi_new = max(2, args.max_len // 8)
+    if args.shared_prefix:
+        # template length deliberately off the page grid so boundary
+        # CoW copies exercise on every admission wave
+        tlen = max(args.page_size + args.page_size // 2,
+                   args.max_len // 2 - args.page_size // 2)
+        return shared_prefix_requests(
+            args.requests, seed=args.seed, vocab=vocab,
+            template_len=min(tlen, args.max_len - 10),
+            suffix_lens=(2, 8), max_new=tuple(args.max_new or (2, hi_new)),
+            temperature=args.temperature, temperature_every=temp_every)
+    prompt_lens = tuple(args.prompt_lens or (2, max(2, args.max_len // 4)))
+    if args.arrival_rate > 0:
+        return poisson_requests(
+            args.requests, seed=args.seed, vocab=vocab,
+            arrival_rate=args.arrival_rate, burst_amp=args.burst_amp,
+            burst_period=args.burst_period, prompt_bounds=prompt_lens,
+            new_bounds=tuple(args.max_new or (1, hi_new)),
+            temperature=args.temperature, temperature_every=temp_every,
+            deadline_ticks=args.deadline_ticks)
+    return mixed_requests(
+        args.requests, seed=args.seed, vocab=vocab, prompt_lens=prompt_lens,
+        max_new=tuple(args.max_new or (2, hi_new)),
+        temperature=args.temperature, temperature_every=temp_every)
+
+
+def serve(eng, args, reqs):
+    """Run ``reqs`` to completion; returns (outputs by uid, seconds on
+    the blocking clock)."""
+    t0 = time.time()
+    if args.arrival_rate > 0 and not args.shared_prefix:
+        outputs = run_arrivals(eng, reqs)
+    else:
+        outputs = run_staggered(eng, staggered_groups(reqs, args.slots))
+    jax.block_until_ready(eng.cache)   # timings are blocking-clock
+    return outputs, time.time() - t0
+
+
+def report(eng, args, reqs, outputs, seconds, mesh) -> None:
+    """Print the run's summary lines; raises SystemExit where the
+    workload's own checks fail (strict mode, prefix sharing, latency)."""
     ntok = sum(len(o) for o in outputs.values())
     print(f"served {args.requests} requests / {ntok} tokens in "
           f"{eng.ticks} ticks (K={args.ticks_per_sync}, "
-          f"attn={args.attn_impl}) = {ntok / dt:.0f} tok/s on "
+          f"attn={args.attn_impl}) = {ntok / seconds:.0f} tok/s on "
           f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")
-    if paged:
+    if isinstance(eng, PagedEngine):
         st = eng.paged_stats()
         print(f"paged KV: pages-in-use high-water {st['pages_hwm']}"
               f"/{eng.num_pages} (page_size={eng.page_size}), "
@@ -269,9 +308,10 @@ def main():
             raise SystemExit(
                 f"latency percentiles empty or incomplete: "
                 f"{summary['completed']}/{args.requests} requests finished")
-    if tracer is not None:
-        path = tracer.save(args.trace_out)
-        print(f"chrome trace ({len(tracer.to_chrome_trace()['traceEvents'])}"
+    if eng.tracer is not None:
+        path = eng.tracer.save(args.trace_out)
+        print(f"chrome trace "
+              f"({len(eng.tracer.to_chrome_trace()['traceEvents'])}"
               f" events) -> {path}")
     if args.verdicts:
         for v in eng.nvm_verdicts():
@@ -280,6 +320,19 @@ def main():
                   f"SOT {v.energy_ratio['SOT']:.3f}   EDP "
                   f"STT {v.edp_ratio['STT']:.3f} / "
                   f"SOT {v.edp_ratio['SOT']:.3f}")
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.list_configs:
+        _list_configs()
+        return
+    enable_compile_cache()
+    mesh, eng = build_engine(args)
+    reqs = make_requests(args, eng.model.cfg.vocab_size)
+    with mesh_context(mesh):
+        outputs, seconds = serve(eng, args, reqs)
+    report(eng, args, reqs, outputs, seconds, mesh)
 
 
 if __name__ == "__main__":

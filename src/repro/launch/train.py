@@ -29,8 +29,9 @@ import time
 import jax
 import numpy as np
 
-from repro.launch.mesh import mesh_context
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import mesh_context
 from repro.data import DataConfig, Pipeline
 from repro.models import build_model
 from repro.optim import AdamW, warmup_cosine
@@ -70,6 +71,7 @@ def main():
                     default=True, help="print train-mode NVM verdicts "
                                        "(fused mode only)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = jax.device_count()
     mesh = remesh(n)

@@ -97,8 +97,10 @@ class Model:
     param_defs: ParamDefs
 
     # ---- params ---------------------------------------------------------
-    def init(self, key: jax.Array) -> Params:
-        return materialize(self.param_defs, key, self.cfg.dtype)
+    def init(self, key: jax.Array, shardings=None) -> Params:
+        """Seeded random parameters; ``shardings`` (path -> Sharding)
+        places each where it lives (see ``materialize``)."""
+        return materialize(self.param_defs, key, self.cfg.dtype, shardings)
 
     def abstract_params(self) -> Params:
         return abstract(self.param_defs, self.cfg.dtype)

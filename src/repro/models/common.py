@@ -40,22 +40,37 @@ def _fan_in(shape: Tuple[int, ...]) -> int:
     return int(np.prod(shape[:-1]))
 
 
-def materialize(defs: ParamDefs, key: jax.Array, dtype: str) -> Params:
-    params: Params = {}
-    keys = jax.random.split(key, max(len(defs), 1))
-    for (name, d), k in zip(sorted(defs.items()), keys):
-        dt = jnp.dtype(d.dtype or dtype)
-        if d.init == "zeros":
-            params[name] = jnp.zeros(d.shape, dt)
-        elif d.init == "ones":
-            params[name] = jnp.ones(d.shape, dt)
-        elif d.init == "const":
-            params[name] = jnp.full(d.shape, d.const, dt)
-        else:
-            scale = d.scale if d.scale is not None else _fan_in(d.shape) ** -0.5
-            params[name] = (jax.random.normal(k, d.shape, jnp.float32)
-                            * scale).astype(dt)
-    return params
+def materialize(defs: ParamDefs, key: jax.Array, dtype: str,
+                shardings: Optional[Dict[str, jax.sharding.Sharding]] = None
+                ) -> Params:
+    """Draw every tensor in one jitted program, directly in its dtype.
+
+    No tensor is drawn in float32 and cast, and with ``shardings`` (path
+    -> Sharding) each lands where it will live, so no device ever holds
+    a second copy; without, all land on the default device.
+    """
+    names = sorted(defs)
+
+    def draw(key):
+        keys = jax.random.split(key, max(len(names), 1))
+        out = {}
+        for name, k in zip(names, keys):
+            d = defs[name]
+            dt = jnp.dtype(d.dtype or dtype)
+            if d.init == "zeros":
+                out[name] = jnp.zeros(d.shape, dt)
+            elif d.init == "ones":
+                out[name] = jnp.ones(d.shape, dt)
+            elif d.init == "const":
+                out[name] = jnp.full(d.shape, d.const, dt)
+            else:
+                scale = (d.scale if d.scale is not None
+                         else _fan_in(d.shape) ** -0.5)
+                out[name] = jax.random.normal(k, d.shape, dt) * jnp.asarray(
+                    scale, dt)
+        return out
+
+    return jax.jit(draw, out_shardings=shardings)(key)
 
 
 def abstract(defs: ParamDefs, dtype: str) -> Params:

@@ -318,17 +318,23 @@ def decoder_forward(
             return (_unembed(cfg, params, x), {"k": ck, "v": cv}, auxs.sum())
 
         # decode (page_table/kv_write_mask are layer-invariant: closed
-        # over, not scanned — the per-layer pool slices are)
-        def body(xc, xs):
-            lp, w, k_l, v_l = xs
+        # over).  The stacked cache rides in the carry and each layer
+        # writes its own slice back in place: scanned as xs -> ys it would
+        # need a second full-size cache buffer for the ys.
+        def body(carry, xs):
+            xc, ck, cv = carry
+            lp, w, i = xs
             y, kv, _ = _decoder_layer(cfg, lp, xc, positions=positions,
-                                      window=w, cache={"k": k_l, "v": v_l},
+                                      window=w, cache={"k": ck[i], "v": cv[i]},
                                       cache_pos=cache_pos, impl=attn_impl,
                                       page_table=page_table,
                                       kv_write_mask=kv_write_mask)
-            return y, (kv["k"], kv["v"])
-        x, (ck, cv) = jax.lax.scan(body, x, (blocks, windows, cache["k"],
-                                             cache["v"]))
+            ck = jax.lax.dynamic_update_index_in_dim(ck, kv["k"], i, 0)
+            cv = jax.lax.dynamic_update_index_in_dim(cv, kv["v"], i, 0)
+            return (y, ck, cv), None
+        layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+        (x, ck, cv), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]), (blocks, windows, layers))
         return _unembed(cfg, params, x), {"k": ck, "v": cv}, aux_total
 
     # unrolled homogeneous stack

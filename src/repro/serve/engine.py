@@ -646,21 +646,14 @@ class Engine:
 
     # ---- traffic accounting --------------------------------------------
     def _analyze(self, jitted, *args):
-        """Roofline terms of the compiled executable.  Failures degrade to
-        None (the engine keeps serving) but warn loudly — a silently empty
-        ``serve_records()`` would erase the NVM-verdict handoff while CI
-        stays green."""
+        """Roofline terms of the compiled executable (None when traffic is
+        not recorded).  A failure raises: a compile the analysis could not
+        make is one the launch would not make either, and a phase missing
+        from ``serve_records()`` would silently change the NVM verdicts."""
         if not self.record_traffic:
             return None
-        try:
-            from repro.launch import roofline as rf
-            return rf.analyze(jitted.lower(*args).compile())
-        except Exception as e:  # pragma: no cover - backend-dependent
-            import warnings
-            warnings.warn(
-                f"serve traffic analysis failed ({e!r}); serve_records() "
-                "will omit this phase", RuntimeWarning, stacklevel=2)
-            return None
+        from repro.launch import roofline as rf
+        return rf.analyze(jitted.lower(*args).compile())
 
     # ---- admission ------------------------------------------------------
     def submit(self, req: Request) -> bool:

@@ -39,10 +39,12 @@ def choose_mesh(num_devices: int,
     return MeshChoice(num_devices, 1, 1)
 
 
-def remesh(num_devices: int):
+def remesh(num_devices: int, devices: Optional[Sequence] = None):
+    """Mesh for ``num_devices`` devices (from ``devices`` when given: a
+    launcher that serves on a subset of the host passes that subset)."""
     c = choose_mesh(num_devices)
     return make_mesh_for(c.devices, model_parallelism=c.model_parallelism,
-                         pods=c.pods)
+                         pods=c.pods, devices=devices)
 
 
 class StragglerMonitor:

@@ -60,6 +60,19 @@ def test_ladder_kernel_matches_numpy_oracle(ways, num_sets, tile):
     assert (np.asarray(got).sum(axis=2) == traces.shape[1]).all()
 
 
+@pytest.mark.parametrize("chunk", [128, 250, 600])
+def test_ladder_kernel_chunked_trace_matches_numpy_oracle(chunk):
+    """LRU state and counters carry across SMEM trace chunks, including
+    a ragged last chunk whose padding must never be simulated."""
+    from repro.kernels import cache_sim
+    traces = np.stack([_zipf_trace(600, 500, seed=s) for s in (2, 3)])
+    got = cache_sim.cache_sim_ladder(jnp.asarray(traces, jnp.int32),
+                                     (3, 20, 33), ways=4, sets_tile=8,
+                                     chunk=chunk, interpret=True)
+    want = ref.cache_sim_ladder_numpy(traces, (3, 20, 33), ways=4)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # --- batched engine vs the retained per-point path --------------------------
 
 

@@ -160,15 +160,21 @@ def test_paged_kernel_ignores_pages_beyond_pos():
 # --- fused sampling ----------------------------------------------------------
 
 
-def test_fused_sample_greedy_bitwise_argmax_with_cross_block_ties():
+@pytest.mark.parametrize("B,V,bv", [
+    (5, 512, 128),       # vocab tiles exactly
+    (9, 1000, 256),      # ragged rows and ragged last vocab block
+    (8, 32064, 2048),    # phi3-mini vocab: no 128-aligned divisor
+])
+def test_fused_sample_greedy_bitwise_argmax_with_cross_block_ties(B, V, bv):
     rng = np.random.default_rng(0)
-    logits = rng.normal(size=(5, 512)).astype(np.float32)
+    logits = rng.normal(size=(B, V)).astype(np.float32)
     logits[1, 100] = logits[1, 300] = 50.0       # tie across blocks
-    logits[2, 0] = logits[2, 511] = 50.0         # tie at both edges
+    logits[2, 0] = logits[2, V - 1] = 50.0       # tie at both edges
+    logits[3, V - 1] = 60.0                      # max in the ragged block
     lg = jnp.asarray(logits)
-    temps = jnp.zeros(5, jnp.float32)
+    temps = jnp.zeros(B, jnp.float32)
     key = jax.random.PRNGKey(42)
-    got = ops.fused_sample(lg, temps, key, bv=128)
+    got = ops.fused_sample(lg, temps, key, bv=bv)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(jnp.argmax(lg, axis=-1)))
 

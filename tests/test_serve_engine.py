@@ -34,6 +34,16 @@ def _workload(n=7, seed=0, **kw):
     return mixed_requests(n, seed=seed, vocab=512, **kw)
 
 
+def _eos_exit_token(outputs):
+    """A token some eos-free output first emits at index >= 1.  With slot
+    isolation that output's prefix is schedule-independent, so an eos run
+    must end that request exactly there, after at least one decode tick
+    (a token also emitted earlier in the same output would end it
+    sooner, often at its first token)."""
+    return next(o[i] for o in outputs.values() for i in range(1, len(o))
+                if o[i] not in o[:i])
+
+
 # --- per-row position vectors (the model-side contract) ---------------------
 
 
@@ -158,12 +168,11 @@ def test_mixed_workload_greedy_parity_vs_reference(mp):
     """Staggered arrivals, uneven prompt/output lengths, eos exits: fused
     outputs == reference outputs, token for token, at K=1 and K=4."""
     model, params = mp
-    # probe the same workload eos-free and pick a token generated at
-    # index >= 1: with slot isolation the prefix is schedule-independent,
-    # so the eos run must truncate that request exactly there
+    # probe the same workload eos-free and pick an eos that ends one
+    # request after a decode tick
     ref = EngineReference(model, params, slots=SLOTS, max_len=MAX_LEN)
     probe_out = run_staggered(ref, staggered_groups(_workload(seed=5), 2))
-    eos = next(t for o in probe_out.values() for t in o[1:])
+    eos = _eos_exit_token(probe_out)
 
     ref = EngineReference(model, params, slots=SLOTS, max_len=MAX_LEN,
                           eos_id=eos)
@@ -185,7 +194,7 @@ def test_mixed_workload_greedy_parity_pallas_engine(mp):
     model, params = mp
     ref = EngineReference(model, params, slots=SLOTS, max_len=MAX_LEN)
     probe_out = run_staggered(ref, staggered_groups(_workload(seed=5), 2))
-    eos = next(t for o in probe_out.values() for t in o[1:])
+    eos = _eos_exit_token(probe_out)
 
     ref = EngineReference(model, params, slots=SLOTS, max_len=MAX_LEN,
                           eos_id=eos)
@@ -209,6 +218,23 @@ def test_attn_impl_validated_and_recorded(mp):
     eng.run()
     decode = next(r for r in eng.serve_records() if r["kind"] == "decode")
     assert decode["attn_impl"] == "pallas_decode"
+
+
+def test_traffic_analysis_failure_raises(mp, monkeypatch):
+    """With record_traffic on, a failed analysis raises instead of leaving
+    its phase out of serve_records() behind a warning."""
+    from repro.launch import roofline
+    model, params = mp
+
+    def broken(compiled):
+        raise RuntimeError("analysis broke")
+
+    monkeypatch.setattr(roofline, "analyze", broken)
+    eng = Engine(model, params, slots=2, max_len=16, ticks_per_sync=2,
+                 record_traffic=True)
+    eng.submit(Request(uid=0, prompt=[1, 2, 3], max_new_tokens=3))
+    with pytest.raises(RuntimeError, match="analysis broke"):
+        eng.run()
 
 
 def test_outputs_are_schedule_independent(mp):
@@ -296,7 +322,7 @@ def test_eos_and_slot_free_tick_parity_vs_reference(mp):
     ref = EngineReference(model, params, slots=SLOTS, max_len=MAX_LEN)
     probe_out = run_staggered(
         ref, staggered_groups(_workload(6, seed=9, max_new=(3, 10)), 2))
-    eos = next(t for o in probe_out.values() for t in o[1:])
+    eos = _eos_exit_token(probe_out)
 
     def ticks_of(engine_cls, **kw):
         reqs = _workload(6, seed=9, max_new=(3, 10))

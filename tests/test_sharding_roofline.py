@@ -7,7 +7,7 @@ pytest.importorskip("hypothesis")  # property tests need hypothesis
 from hypothesis import given, settings, strategies as st
 
 from repro.launch.hlo_analysis import analyze_hlo, parse_hlo
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, Roofline
+from repro.launch.roofline import MODELED_KIND, PEAKS, Roofline
 from repro.sharding.rules import default_rules, spec_for
 
 MESH16 = {"data": 16, "model": 16}
@@ -123,14 +123,37 @@ def test_hlo_analyzer_on_real_compiled_scan():
 
 
 def test_roofline_terms():
-    r = Roofline(flops_per_device=PEAK_FLOPS, bytes_per_device=HBM_BW,
-                 collective_bytes=2 * ICI_BW, collectives={},
-                 collective_counts={}, temp_bytes=0, arg_bytes=0)
+    pk = PEAKS[MODELED_KIND]
+    r = Roofline(flops_per_device=pk.flops, bytes_per_device=pk.hbm_bw,
+                 collective_bytes=2 * pk.ici_bw, collectives={},
+                 collective_counts={}, temp_bytes=0, arg_bytes=0,
+                 device_kind=MODELED_KIND)
     assert r.compute_s == pytest.approx(1.0)
     assert r.memory_s == pytest.approx(1.0)
     assert r.collective_s == pytest.approx(2.0)
     assert r.dominant == "collective"
-    assert r.model_flops_util(PEAK_FLOPS) == pytest.approx(0.5)
+    assert r.model_flops_util(pk.flops) == pytest.approx(0.5)
+
+
+def test_roofline_prices_by_device_kind():
+    """CPU compiles are priced as the modeled v5e; a TPU kind missing
+    from the table raises instead of borrowing another chip's peaks."""
+    from repro.launch.roofline import (UnknownChipError, analyze,
+                                       priced_kind)
+    cpu = jax.devices("cpu")[0]
+    assert priced_kind(cpu) == MODELED_KIND
+    compiled = jax.jit(lambda x: x @ x).lower(
+        jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
+    assert analyze(compiled).device_kind == MODELED_KIND
+
+    class FakeTpu:
+        platform, device_kind = "tpu", "TPU v99"
+    r = Roofline(flops_per_device=1.0, bytes_per_device=1.0,
+                 collective_bytes=0.0, collectives={}, collective_counts={},
+                 temp_bytes=0, arg_bytes=0,
+                 device_kind=priced_kind(FakeTpu()))
+    with pytest.raises(UnknownChipError, match="TPU v99"):
+        r.compute_s
 
 
 # --- crosslayer -----------------------------------------------------------------
