@@ -120,6 +120,10 @@ class Request:
     when the admission prefill's tokens land on the host, ``done_time``
     at the drain that surfaces the final token — so wall-clock TTFT/TPOT
     include the K-tick drain cadence a client would really see.
+    ``dequeue_time`` is when admission took the request off the queue
+    for the prefill that served it, so ``submit_time -> dequeue_time``
+    is its queue wait, ``dequeue_time -> first_token_time`` its
+    admission and ``first_token_time -> done_time`` its decode.
     ``arrival`` is the intended arrival time in ticks for traffic-
     generator workloads (``serve/workload.py``); tick-domain latencies
     are measured from it when set, else from ``submit_tick``.
@@ -160,6 +164,7 @@ class Request:
     submit_time: Optional[float] = None
     admit_tick: Optional[int] = None
     admit_time: Optional[float] = None
+    dequeue_time: Optional[float] = None
     first_token_tick: Optional[int] = None
     first_token_time: Optional[float] = None
     done_time: Optional[float] = None
@@ -174,15 +179,19 @@ class Request:
     def terminal(self) -> bool:
         return self.state in TERMINAL_STATES
 
-    def _mark_admitted(self, tick: int, now: float) -> None:
-        """Stamp admission == first-token emission (see class docstring);
-        both engines route through here so the tick domains cannot drift.
-        Stamps only the FIRST admission: a requeued request (retry /
-        preemption) keeps its original TTFT."""
+    def _mark_admitted(self, tick: int, now: float,
+                       dequeued: float) -> None:
+        """Stamp admission == first-token emission (see class docstring)
+        and the ``dequeued`` time the admission took the request off the
+        queue; every engine routes through here so the tick domains
+        cannot drift.  Stamps only the FIRST admission: a requeued
+        request (retry / preemption) keeps its original TTFT and queue
+        wait."""
         self.state = RUNNING
         if self.admit_tick is None:
             self.admit_tick = self.first_token_tick = tick
             self.admit_time = self.first_token_time = now
+            self.dequeue_time = dequeued
 
     def _finalize(self, state: str, tick: int, now: float,
                   reason: Optional[str] = None) -> None:
@@ -382,8 +391,9 @@ class Engine:
         # requests, so tick-domain TTFT reflects prompt-processing cost
         # (benchmarks enable it on both legs to expose prefix-sharing wins)
         self.charge_prefill_ticks = bool(charge_prefill_ticks)
-        # optional serve.telemetry.Tracer: records prefill / decode-window
-        # / host-drain spans for chrome://tracing export (DESIGN.md §14)
+        # optional serve.telemetry.Tracer: records the spans that tile
+        # each step (admission phases, prefill, decode window, host
+        # drain) for chrome://tracing export (DESIGN.md §14)
         self.tracer = tracer
         # resilience layer (DESIGN.md §16): admission control, bounded
         # window retry, per-slot output health checks, and an optional
@@ -447,6 +457,7 @@ class Engine:
             "temps": jnp.zeros(self.slots, jnp.float32),
         }
         self.ticks = 0
+        self._t_step = self._t_mark = 0.0   # step entry / last boundary
         self._counts = {"decode_ticks": 0, "prefill_calls": {}}
         self._poison_host = np.zeros(self.slots, bool)   # chaos NaN operand
         self._degraded = False      # sticky eager-window fallback mode
@@ -655,6 +666,32 @@ class Engine:
         from repro.launch import roofline as rf
         return rf.analyze(jitted.lower(*args).compile())
 
+    # ---- step timeline (tracer attached) --------------------------------
+    def _leaf(self, name: str, cat: str, end: Optional[float] = None,
+              args: Optional[dict] = None) -> float:
+        """Record the next leaf span of ``step``: from the previous
+        boundary to ``end`` (a stamp the caller already took) or to now.
+        Leaves abut, so together they tile the step.  Returns the new
+        boundary."""
+        if end is None:
+            end = time.perf_counter()
+        self.tracer.span(name, cat, self._t_mark, end, args=args)
+        self._t_mark = end
+        return end
+
+    def _trace_admit(self, leaf: str, uids: List[int], queued: int,
+                     decoding: int, deferred: int = 0,
+                     shed: int = 0) -> None:
+        """Close an admission: the ``leaf`` span of the bookkeeping after
+        the prefill, then the ``admit`` span over every admission leaf
+        since step entry.  ``decoding`` counts the slots already running
+        a request, whose decode waits on this admission."""
+        t = self._leaf(leaf, leaf)
+        self.tracer.span("admit", "admit", self._t_step, t, args={
+            "tick": self.ticks, "queued": queued, "admitted": len(uids),
+            "deferred": deferred, "shed": shed, "uids": uids,
+            "decoding": decoding})
+
     # ---- admission ------------------------------------------------------
     def submit(self, req: Request) -> bool:
         """Queue a request; never raises.  Malformed requests finalize as
@@ -672,12 +709,16 @@ class Engine:
         schedule-independent, so the continuation is bitwise what an
         uninterrupted run would have emitted."""
         self._last_admitted = 0
+        queued = len(self._queue)
         _drop_expired(self)
         free = [i for i in range(self.slots) if self.slot_req[i] is None]
         take = min(len(free), len(self._queue))
         if take == 0:
             return 0
         pairs = [(free[i], self._queue.popleft()) for i in range(take)]
+        t_deq = time.perf_counter()
+        if self.tracer is not None:
+            self._leaf("plan", "plan", end=t_deq)
         eff = {s: list(r.prompt) + list(r.output) for s, r in pairs}
         P = min(self.max_len,
                 _next_pow2(max(len(e) for e in eff.values())))
@@ -710,6 +751,8 @@ class Engine:
             self._traffic["prefill"][P] = self._analyze(
                 self._prefill_jit, *args)
         t_launch = time.perf_counter()
+        if self.tracer is not None:
+            self._leaf("prefill_inputs", "prefill_inputs", end=t_launch)
         self.cache, self._state, self.key, t0, done0, ok0 = \
             self._prefill_jit(*args)
         self._counts["prefill_calls"][P] = \
@@ -717,15 +760,16 @@ class Engine:
         t0, done0, ok0 = np.asarray(t0), np.asarray(done0), np.asarray(ok0)
         now = time.perf_counter()   # t0/done0 observed on the host
         if self.tracer is not None:
-            self.tracer.span(f"prefill P={P}", "prefill", t_launch, now,
-                             args={"tick": self.ticks, "admitted": take,
-                                   "padded_len": P})
+            self._leaf(f"prefill P={P}", "prefill", end=now,
+                       args={"tick": self.ticks, "admitted": take,
+                             "padded_len": P,
+                             "uids": [r.uid for _, r in pairs]})
         if self.charge_prefill_ticks:
             self.ticks += -(-int(lens.sum()) // self.slots)
         bad0: Dict[int, int] = {}
         for s, r in pairs:
             self.slot_req[s] = r
-            r._mark_admitted(self.ticks, now)
+            r._mark_admitted(self.ticks, now, t_deq)
             if self.health_check and not ok0[s]:
                 bad0[s] = 0      # poisoned prefill: discard t0, requeue
                 continue
@@ -737,6 +781,9 @@ class Engine:
         self._last_admitted = take
         if bad0:
             self._quarantine(bad0, now)
+        if self.tracer is not None:
+            self._trace_admit("slots", [r.uid for _, r in pairs], queued,
+                              self.slots - len(free))
         return take
 
     def _release_slot(self, s: int) -> None:
@@ -751,9 +798,12 @@ class Engine:
             mask[s] = True
             self.cache = self._reset_jit(self.cache, jnp.asarray(mask))
 
-    def _pre_window(self) -> None:
+    def _pre_window(self) -> Optional[dict]:
         """Hook called right before a decode window launches (PagedEngine
-        uploads a dirty page table and measures page sharing)."""
+        uploads a dirty page table and measures page sharing).  Returns
+        extra args for the ``pre_window`` span when a tracer is
+        attached."""
+        return None
 
     def _extra_window_args(self) -> tuple:
         """Extra device operands for ``_window`` (PagedEngine: the page
@@ -884,13 +934,24 @@ class Engine:
     # ---- engine loop ----------------------------------------------------
     def step(self) -> int:
         """One sync window: admit + K fused ticks + drain.  Returns the
-        number of sequences active during the window."""
+        number of sequences active during the window.
+
+        With a tracer attached, the step's leaf spans tile it from entry
+        to return: the admission leaves under an ``admit`` span (when
+        anything is admitted), then ``pre_window``, ``decode_window``
+        and ``host_drain`` (DESIGN.md §14)."""
+        if self.tracer is not None:
+            self._t_step = self._t_mark = time.perf_counter()
         self._fire_faults("pre_admit")
         self._admit()
         n_active = sum(r is not None for r in self.slot_req)
         if n_active == 0:
+            if self.tracer is not None and not self._last_admitted:
+                # a scan that admitted nothing: no slot, or no pages
+                self._leaf("plan", "plan", args={
+                    "tick": self.ticks, "queued": len(self._queue)})
             return 0
-        self._pre_window()
+        pre_args = self._pre_window()
         self._fire_faults("pre_window")
         # copy before transfer: on CPU jnp.asarray may alias the numpy
         # buffer, and the one-shot clear below would race the async
@@ -901,7 +962,12 @@ class Engine:
                 *extra)
         if self._traffic["decode"] is None and self.record_traffic:
             self._traffic["decode"] = self._analyze(self._window_jit, *args)
+        tick = self.ticks
         t_launch = time.perf_counter()
+        if self.tracer is not None:
+            uids = [r.uid for r in self.slot_req if r is not None]
+            self._leaf("pre_window", "pre_window", end=t_launch,
+                       args=dict(pre_args or {}, tick=tick))
         self.cache, self._state, self.key, toks, fins, oks = \
             self._launch_window(args)
         if self._poison_host.any():
@@ -938,20 +1004,17 @@ class Engine:
                     r._mark_done(self.ticks + t, now)
                     self._release_slot(s)
                     self.slot_req[s] = None
-        if self.tracer is not None:
-            t_end = time.perf_counter()
-            self.tracer.span(
-                "decode_window", "decode", t_launch, now,
-                args={"tick": self.ticks, "K": self.ticks_per_sync,
-                      "active": n_active})
-            self.tracer.span("host_drain", "host", now, t_end,
-                             args={"tick": self.ticks})
-            self.tracer.counter("active_slots", {"active": n_active},
-                                t_launch)
         self.ticks += self.ticks_per_sync
         if bad:
             self._quarantine(bad, now)
         self._expire_running(now)
+        if self.tracer is not None:
+            self._leaf("decode_window", "decode", end=now,
+                       args={"tick": tick, "K": self.ticks_per_sync,
+                             "active": n_active, "uids": uids})
+            self._leaf("host_drain", "host", args={"tick": tick})
+            self.tracer.counter("active_slots", {"active": n_active},
+                                t_launch)
         return n_active
 
     def run(self, max_ticks: int = 10_000) -> int:
@@ -1137,7 +1200,7 @@ class PagedEngine(Engine):
     def _extra_window_args(self) -> tuple:
         return (self._pt_dev,)
 
-    def _pre_window(self) -> None:
+    def _pre_window(self) -> Optional[dict]:
         if self._pt_dirty:
             self._pt_dev = jnp.asarray(self._pt_host)
             self._pt_dirty = False
@@ -1150,16 +1213,16 @@ class PagedEngine(Engine):
             pos = len(r.prompt) + len(r.output) - 1
             n = pages_for(min(pos + 1, self.max_len), self.page_size)
             mapped.extend(self._pt_host[s, :n].tolist())
-        if mapped:
-            frac = len(set(mapped)) / len(mapped)
-            self._upf_sum += frac
-            self._upf_windows += 1
-            if self.tracer is not None:
-                self.tracer.instant(
-                    "page_gather", "paged", time.perf_counter(),
-                    args={"tick": self.ticks, "mapped": len(mapped),
-                          "unique": len(set(mapped)),
-                          "unique_page_fraction": frac})
+        if not mapped:
+            return None
+        unique = len(set(mapped))
+        frac = unique / len(mapped)
+        self._upf_sum += frac
+        self._upf_windows += 1
+        if self.tracer is None:
+            return None
+        return {"mapped": len(mapped), "unique": unique,
+                "unique_page_fraction": frac}
 
     def _release_slot(self, s: int) -> None:
         for p in self._slot_pages[s]:
@@ -1253,11 +1316,14 @@ class PagedEngine(Engine):
         stall guard this makes pool exhaustion a latency event, not a
         deadlock."""
         self._last_admitted = 0
+        queued = len(self._queue)
         _drop_expired(self)
         free = [s for s in range(self.slots) if self.slot_req[s] is None]
+        decoding = self.slots - len(free)
         pol = self.shed_policy
         pairs = []
         deferred: List[Request] = []
+        shed = 0
         while free and self._queue:
             r = self._queue.popleft()
             plan = self._plan(r)
@@ -1272,6 +1338,7 @@ class PagedEngine(Engine):
                                 f"admission attempts (last shortfall: "
                                 f"wanted {want} pages, {have} free)"))
                     self._rstats["shed"] += 1
+                    shed += 1
                 else:
                     deferred.append(r)
                 continue
@@ -1280,11 +1347,9 @@ class PagedEngine(Engine):
             self._queue.appendleft(r)
         if not pairs:
             return 0
-        t_admit = time.perf_counter()
+        t_admit = time.perf_counter()   # the admitted leave the queue
         if self.tracer is not None:
-            self.tracer.begin("admit", "prefill", t_admit,
-                              args={"tick": self.ticks,
-                                    "admitted": len(pairs)})
+            self._leaf("plan", "plan", end=t_admit)
         # batched CoW device copies, then drop the boundary pins
         cows = [p["cow"] for _, _, p in pairs if p["cow"] is not None]
         if cows:
@@ -1295,11 +1360,8 @@ class PagedEngine(Engine):
             for _, _, p in pairs:
                 if p["boundary_pin"] is not None:
                     self.pool.release(p["boundary_pin"])
-                if self.tracer is not None and p["cow"] is not None:
-                    self.tracer.instant(
-                        "cow_copy", "paged", time.perf_counter(),
-                        args={"src": int(p["cow"][0]),
-                              "dst": int(p["cow"][1])})
+            if self.tracer is not None:
+                self._leaf("cow", "cow", args={"copies": len(cows)})
         # page tables: the slot holds one reference per mapped page
         for s, r, p in pairs:
             self._slot_pages[s] = list(p["pages"])
@@ -1338,6 +1400,8 @@ class PagedEngine(Engine):
             self._traffic["prefill"][S] = self._analyze(
                 self._prefill_jit, *args)
         t_launch = time.perf_counter()
+        if self.tracer is not None:
+            self._leaf("prefill_inputs", "prefill_inputs", end=t_launch)
         self.cache, self._state, self.key, t0, done0, ok0 = \
             self._prefill_jit(*args)
         self._counts["prefill_calls"][S] = \
@@ -1345,18 +1409,19 @@ class PagedEngine(Engine):
         t0, done0, ok0 = np.asarray(t0), np.asarray(done0), np.asarray(ok0)
         now = time.perf_counter()
         if self.tracer is not None:
-            self.tracer.span(
-                f"prefill_chunk S={S}", "prefill", t_launch, now,
+            self._leaf(
+                f"prefill_chunk S={S}", "prefill", end=now,
                 args={"tick": self.ticks, "admitted": len(pairs),
                       "padded_len": S,
                       "suffix_tokens": int(suf_lens.sum()),
-                      "shared_tokens": int((full_lens - suf_lens).sum())})
+                      "shared_tokens": int((full_lens - suf_lens).sum()),
+                      "uids": [r.uid for _, r, _ in pairs]})
         if self.charge_prefill_ticks:
             self.ticks += -(-int(suf_lens.sum()) // self.slots)
         bad0: Dict[int, int] = {}
         for s, r, p in pairs:
             self.slot_req[s] = r
-            r._mark_admitted(self.ticks, now)
+            r._mark_admitted(self.ticks, now, t_admit)
             if self.health_check and not ok0[s]:
                 # poisoned prefill (a shared or recycled page carried
                 # corrupt KV): discard t0 and requeue via quarantine —
@@ -1374,12 +1439,12 @@ class PagedEngine(Engine):
                 r._mark_done(self.ticks, now)
                 self._release_slot(s)
                 self.slot_req[s] = None
-        if self.tracer is not None:
-            self.tracer.end(time.perf_counter(),
-                            args={"pages_in_use": self.pool.in_use})
         self._last_admitted = len(pairs)
         if bad0:
             self._quarantine(bad0, now)
+        if self.tracer is not None:
+            self._trace_admit("tree", [r.uid for _, r, _ in pairs], queued,
+                              decoding, len(deferred), shed)
         return len(pairs)
 
     def _prefill_prog(self, params, cache, state, tokens, pt, starts,
@@ -1527,6 +1592,7 @@ class EngineReference:
         """Per-token prefill (the seed loop), slot-isolated.  Requeued
         requests (e.g. crash resubmission) resume from their effective
         prompt ``prompt + output``, mirroring ``Engine._admit``."""
+        t_deq = time.perf_counter()
         self.slot_req[slot] = req
         eff = list(req.prompt) + list(req.output)
         sel = (jnp.arange(self.slots) == slot)
@@ -1569,7 +1635,7 @@ class EngineReference:
             lg = logits
         t0 = self._sample(np.asarray(lg)[slot, -1].astype(np.float32),
                           req.temperature)
-        req._mark_admitted(self.ticks, time.perf_counter())
+        req._mark_admitted(self.ticks, time.perf_counter(), t_deq)
         req.output.append(t0)
         self._last[slot] = t0
         self._pos[slot] = len(eff)

@@ -5,9 +5,11 @@ Two independent pieces (DESIGN.md §14):
 * **Latency accounting.**  ``Request`` (serve/engine.py) carries
   submit/admit/first-token/done stamps in BOTH time domains — engine
   ticks (deterministic, schedule-comparable across engines) and wall
-  clock (``time.perf_counter``, what a client actually waits).  This
-  module turns a finished request set into the three serving metrics a
-  production SLO is written against:
+  clock (``time.perf_counter``, what a client actually waits) — plus a
+  wall-clock ``dequeue_time``, when admission took it off the queue.
+  This module turns a finished request set into the three serving
+  metrics a production SLO is written against, and the queue wait
+  (``queue_s``, dequeue − submit) that splits TTFT:
 
     TTFT  time-to-first-token: first_token − reference point (the
           request's intended ``arrival`` when a traffic generator set
@@ -22,12 +24,14 @@ Two independent pieces (DESIGN.md §14):
   estimator (numpy's default) implemented here so a hand-computed trace
   can pin it in tests.
 
-* **Chrome-trace export.**  ``Tracer`` collects engine spans — batched
-  prefill calls, fused decode windows, host drains — plus an
-  active-slots counter track, and serializes them as Trace Event JSON
-  (``chrome://tracing`` / Perfetto "X"/"C"/"M" events, microsecond
-  timestamps).  The engine calls ``span``/``counter`` only when a tracer
-  is attached, so the hot path pays nothing by default.
+* **Chrome-trace export.**  ``Tracer`` collects engine spans — the
+  leaves that tile each engine step (admission phases, batched prefill,
+  fused decode window, host drain) and the ``admit`` span around the
+  admission leaves — plus an active-slots counter track, and serializes
+  them as Trace Event JSON (``chrome://tracing`` / Perfetto "X"/"C"/"M"
+  events, microsecond timestamps; X spans nest by time).  The engine
+  calls ``span``/``counter`` only when a tracer is attached, so the hot
+  path pays nothing by default.
 """
 from __future__ import annotations
 
@@ -69,14 +73,16 @@ def summarize(xs: Sequence[float],
 
 
 def request_latency(req: Request) -> Optional[Dict[str, Dict[str, float]]]:
-    """Per-request {wall: {ttft_s, tpot_s?, e2e_s}, ticks: {...}} or None
-    if the request has not finished (or predates the stamping engine)."""
+    """Per-request {wall: {ttft_s, queue_s, tpot_s?, e2e_s}, ticks: {...}}
+    or None if the request has not finished (or predates the stamping
+    engine)."""
     if not (req.done and req.done_time is not None
             and req.first_token_time is not None
             and req.submit_time is not None):
         return None
     n = len(req.output)
     wall = {"ttft_s": req.first_token_time - req.submit_time,
+            "queue_s": req.dequeue_time - req.submit_time,
             "e2e_s": req.done_time - req.submit_time}
     # tick-domain latencies measure from the intended arrival when the
     # traffic generator set one (charging the sync-cadence admission
@@ -94,9 +100,10 @@ def latency_summary(reqs: Iterable[Request],
                     qs: Sequence[float] = PERCENTILES) -> dict:
     """Aggregate TTFT/TPOT/E2E percentiles over finished requests.
 
-    Returns {"n", "completed", "tokens", "wall": {ttft_s/tpot_s/e2e_s ->
-    summarize()}, "ticks": {ttft/tpot/e2e -> summarize()}}; requests that
-    never finished count in ``n`` but not in the percentiles.
+    Returns {"n", "completed", "tokens", "wall": {ttft_s/queue_s/tpot_s/
+    e2e_s -> summarize()}, "ticks": {ttft/tpot/e2e -> summarize()}};
+    requests that never finished count in ``n`` but not in the
+    percentiles.
     """
     reqs = list(reqs)
     per = [(r, request_latency(r)) for r in reqs]
@@ -128,9 +135,6 @@ def latency_summary(reqs: Iterable[Request],
 # ---- chrome://tracing export ------------------------------------------------
 
 _REQUIRED_BY_PHASE = {"X": ("name", "ts", "dur", "pid", "tid"),
-                      "B": ("name", "ts", "pid", "tid"),
-                      "E": ("ts", "pid", "tid"),
-                      "i": ("name", "ts", "pid"),
                       "C": ("name", "ts", "pid"),
                       "M": ("name", "pid")}
 
@@ -147,9 +151,6 @@ class Tracer:
         self.name = name
         self._spans: List[dict] = []      # (name, cat, t0, t1, tid, args)
         self._counters: List[dict] = []   # (name, values, t, tid)
-        self._nested: List[dict] = []     # "B"/"E" duration events, in order
-        self._instants: List[dict] = []   # "i" point events
-        self._open: List[dict] = []       # begin() stack awaiting end()
 
     def span(self, name: str, cat: str, start_s: float, end_s: float,
              tid: int = 0, args: Optional[dict] = None) -> None:
@@ -158,41 +159,6 @@ class Tracer:
         self._spans.append({"name": name, "cat": cat, "t0": start_s,
                             "t1": end_s, "tid": tid, "args": args or {}})
 
-    # -- nested spans (paged engine: admit > prefill-chunk > CoW ...) --------
-
-    def begin(self, name: str, cat: str, when_s: float, tid: int = 0,
-              args: Optional[dict] = None) -> None:
-        """Open a nested span ("B" phase); close with ``end()``.  Unlike
-        ``span``, begin/end pairs may enclose other spans and instants —
-        the viewer stacks them by arrival order per thread."""
-        ev = {"ph": "B", "name": name, "cat": cat, "t": when_s, "tid": tid,
-              "args": args or {}}
-        self._open.append(ev)
-        self._nested.append(ev)
-
-    def end(self, when_s: float, tid: int = 0,
-            args: Optional[dict] = None) -> None:
-        """Close the innermost open ``begin()`` span ("E" phase)."""
-        if not self._open:
-            raise ValueError("end() without a matching begin()")
-        opened = self._open[-1]
-        if when_s < opened["t"]:
-            # raise BEFORE popping so a rejected end() leaves the span
-            # open instead of orphaning its "B" event in the trace
-            raise ValueError(
-                f"span {opened['name']!r}: end {when_s} < begin "
-                f"{opened['t']}")
-        self._open.pop()
-        self._nested.append({"ph": "E", "name": opened["name"],
-                             "cat": opened["cat"], "t": when_s, "tid": tid,
-                             "args": args or {}})
-
-    def instant(self, name: str, cat: str, when_s: float, tid: int = 0,
-                args: Optional[dict] = None) -> None:
-        """Point-in-time event ("i" phase) — CoW copies, page gathers."""
-        self._instants.append({"name": name, "cat": cat, "t": when_s,
-                               "tid": tid, "args": args or {}})
-
     def counter(self, name: str, values: Dict[str, float], when_s: float,
                 tid: int = 0) -> None:
         self._counters.append({"name": name, "values": dict(values),
@@ -200,16 +166,10 @@ class Tracer:
 
     def _origin(self) -> float:
         times = ([s["t0"] for s in self._spans]
-                 + [c["t"] for c in self._counters]
-                 + [e["t"] for e in self._nested]
-                 + [e["t"] for e in self._instants])
+                 + [c["t"] for c in self._counters])
         return min(times) if times else 0.0
 
     def to_chrome_trace(self) -> dict:
-        if self._open:
-            raise ValueError(
-                f"unclosed begin() spans: "
-                f"{[e['name'] for e in self._open]}")
         origin = self._origin()
         us = lambda t: (t - origin) * 1e6   # noqa: E731
         events: List[dict] = [
@@ -222,14 +182,6 @@ class Tracer:
             events.append({"ph": "X", "name": s["name"], "cat": s["cat"],
                            "ts": us(s["t0"]), "dur": us(s["t1"]) - us(s["t0"]),
                            "pid": 0, "tid": s["tid"], "args": s["args"]})
-        for e in self._nested:   # emitted in call order (B/E pairing)
-            events.append({"ph": e["ph"], "name": e["name"], "cat": e["cat"],
-                           "ts": us(e["t"]), "pid": 0, "tid": e["tid"],
-                           "args": e["args"]})
-        for e in self._instants:
-            events.append({"ph": "i", "name": e["name"], "cat": e["cat"],
-                           "ts": us(e["t"]), "pid": 0, "tid": e["tid"],
-                           "s": "t", "args": e["args"]})
         for c in self._counters:
             events.append({"ph": "C", "name": c["name"], "ts": us(c["t"]),
                            "pid": 0, "tid": c["tid"], "args": c["values"]})
@@ -247,15 +199,13 @@ class Tracer:
 def validate_chrome_trace(obj: dict) -> None:
     """Raise ValueError unless ``obj`` is structurally valid Trace Event
     JSON (the subset this exporter emits): a ``traceEvents`` list whose
-    events carry a known ``ph``, the per-phase required keys,
-    non-negative numeric ``ts``/``dur``, and balanced "B"/"E" nesting
-    per (pid, tid) track in list order."""
+    events carry a known ``ph``, the per-phase required keys and
+    non-negative numeric ``ts``/``dur``."""
     if not isinstance(obj, dict) or "traceEvents" not in obj:
         raise ValueError("trace must be a dict with a 'traceEvents' list")
     events = obj["traceEvents"]
     if not isinstance(events, list):
         raise ValueError("'traceEvents' must be a list")
-    depth: Dict[tuple, int] = {}
     for i, ev in enumerate(events):
         ph = ev.get("ph")
         if ph not in _REQUIRED_BY_PHASE:
@@ -268,14 +218,3 @@ def validate_chrome_trace(obj: dict) -> None:
                             or ev[k] < 0):
                 raise ValueError(f"event {i}: {k}={ev[k]!r} must be a "
                                  "non-negative number")
-        if ph in ("B", "E"):
-            track = (ev.get("pid"), ev.get("tid"))
-            d = depth.get(track, 0) + (1 if ph == "B" else -1)
-            if d < 0:
-                raise ValueError(
-                    f"event {i}: 'E' without a matching 'B' on track "
-                    f"{track}")
-            depth[track] = d
-    open_tracks = {t: d for t, d in depth.items() if d}
-    if open_tracks:
-        raise ValueError(f"unbalanced 'B' spans left open: {open_tracks}")

@@ -57,12 +57,12 @@ def test_summarize_keys():
 
 
 def _stamped(uid=0, *, arrival=None, submit_tick=0, submit_time=10.0,
-             admit_tick=2, admit_time=10.5, done_tick=6, done_time=11.3,
-             n_tokens=5):
+             dequeue_time=10.2, admit_tick=2, admit_time=10.5, done_tick=6,
+             done_time=11.3, n_tokens=5):
     r = Request(uid=uid, prompt=[1, 2], max_new_tokens=n_tokens,
                 output=list(range(n_tokens)), arrival=arrival)
     r.submit_tick, r.submit_time = submit_tick, submit_time
-    r._mark_admitted(admit_tick, admit_time)
+    r._mark_admitted(admit_tick, admit_time, dequeue_time)
     r._mark_done(done_tick, done_time)
     return r
 
@@ -110,6 +110,24 @@ def test_latency_summary_counts_and_percentiles():
     assert s["ticks"]["ttft"]["p50"] == 2
 
 
+def test_latency_summary_reports_queue_wait():
+    # queue waits (dequeue - submit) of 0.1, 0.2, 0.3 and 0.4 s
+    reqs = [_stamped(uid=i, dequeue_time=10.1 + 0.1 * i) for i in range(4)]
+    assert request_latency(reqs[1])["wall"]["queue_s"] == pytest.approx(0.2)
+    q = latency_summary(reqs)["wall"]["queue_s"]
+    assert set(q) == {"p50", "p95", "p99", "mean", "max"}
+    assert q["p50"] == pytest.approx(0.25)
+    assert q["p95"] == pytest.approx(0.385)
+    assert q["max"] == pytest.approx(0.4)
+
+
+def test_requeued_request_keeps_its_first_queue_stamp():
+    r = Request(uid=0, prompt=[1, 2], max_new_tokens=4)
+    r._mark_admitted(2, 10.5, 10.2)
+    r._mark_admitted(5, 12.0, 11.9)     # a retry's second admission
+    assert (r.dequeue_time, r.admit_time) == (10.2, 10.5)
+
+
 def test_latency_summary_empty():
     s = latency_summary([])
     assert s["n"] == 0 and s["completed"] == 0
@@ -148,62 +166,12 @@ def test_tracer_rejects_negative_span():
 def test_validate_chrome_trace_rejects_malformed():
     with pytest.raises(ValueError, match="traceEvents"):
         validate_chrome_trace({"events": []})
-    with pytest.raises(ValueError, match="phase"):
-        validate_chrome_trace({"traceEvents": [{"ph": "Z"}]})
+    for ph in ("Z", "B", "E", "i"):       # the exporter emits X/C/M only
+        with pytest.raises(ValueError, match="phase"):
+            validate_chrome_trace({"traceEvents": [{"ph": ph}]})
     with pytest.raises(ValueError, match="missing"):
         validate_chrome_trace({"traceEvents": [{"ph": "X", "name": "a"}]})
     with pytest.raises(ValueError, match="non-negative"):
         validate_chrome_trace({"traceEvents": [
             {"ph": "X", "name": "a", "ts": -1.0, "dur": 1.0,
              "pid": 0, "tid": 0}]})
-
-
-# --- nested begin/end spans + instants (paged-engine tracing) ---------------
-
-
-def test_tracer_nested_spans_and_instants(tmp_path):
-    tr = Tracer(name="t")
-    tr.begin("admit", "serve", 10.0, args={"n": 2})
-    tr.begin("prefill_chunk S=8", "serve", 10.1)
-    tr.instant("cow_copy", "serve", 10.15, args={"pairs": 1})
-    tr.end(10.3)
-    tr.instant("page_gather", "serve", 10.35, args={"upf": 0.5})
-    tr.end(10.4, args={"pages_in_use": 7})
-    trace = tr.to_chrome_trace()
-    validate_chrome_trace(trace)
-    bs = [e for e in trace["traceEvents"] if e["ph"] == "B"]
-    es = [e for e in trace["traceEvents"] if e["ph"] == "E"]
-    ins = [e for e in trace["traceEvents"] if e["ph"] == "i"]
-    assert [e["name"] for e in bs] == ["admit", "prefill_chunk S=8"]
-    assert len(es) == 2
-    # E events close innermost-first: prefill end (10.3) precedes admit
-    # end (10.4) in call order, and end() args ride on the E event
-    assert es[0]["ts"] < es[1]["ts"]
-    assert es[1]["args"] == {"pages_in_use": 7}
-    assert [e["name"] for e in ins] == ["cow_copy", "page_gather"]
-    assert all(e["s"] == "t" for e in ins)
-    path = tr.save(tmp_path / "nested.json")
-    validate_chrome_trace(json.loads(path.read_text()))
-
-
-def test_tracer_begin_end_misuse_rejected():
-    tr = Tracer()
-    with pytest.raises(ValueError, match="without"):
-        tr.end(1.0)
-    tr.begin("a", "c", 2.0)
-    with pytest.raises(ValueError, match="< begin"):
-        tr.end(1.0)          # end earlier than its begin: span stays open
-    with pytest.raises(ValueError, match="unclosed"):
-        tr.to_chrome_trace()  # "a" still open
-    tr.end(3.0)
-    validate_chrome_trace(tr.to_chrome_trace())
-
-
-def test_validate_chrome_trace_rejects_unbalanced_spans():
-    base = {"name": "a", "cat": "c", "ts": 0.0, "pid": 0, "tid": 0}
-    with pytest.raises(ValueError, match="E"):
-        validate_chrome_trace(
-            {"traceEvents": [{**base, "ph": "E"}]})
-    with pytest.raises(ValueError, match="unbalanced|unclosed"):
-        validate_chrome_trace(
-            {"traceEvents": [{**base, "ph": "B"}]})
