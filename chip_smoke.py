@@ -188,16 +188,20 @@ def kernel_phase(seed: int = 0) -> None:
     _check("paged", err <= ATTN_TOL, f"max|err| {err:.3g}", t0)
 
     t0 = time.perf_counter()
-    o, ckp, cvp = ops.paged_decode_attention_fused(q, kp, vp, nk, nv, pt,
-                                                   pos, win)
+    # the fused kernel takes layer-stacked pools: write layer 1 of two,
+    # whose layer 0 must keep its bits
+    kst, vst = jnp.stack([vp, kp]), jnp.stack([kp, vp])
+    o, ckp, cvp = ops.paged_decode_attention_fused(q, kst, vst, nk, nv, pt,
+                                                   pos, win, 1)
     page, row = pt[rows, pos // PAGE], pos % PAGE
-    kp_ref, vp_ref = kp.at[page, row].set(nk), vp.at[page, row].set(nv)
-    err = _max_err(o, ref.paged_decode_attention_ref(q, kp_ref, vp_ref, pt,
-                                                     pos))
+    kp_ref, vp_ref = (kst.at[1, page, row].set(nk),
+                      vst.at[1, page, row].set(nv))
+    err = _max_err(o, ref.paged_decode_attention_ref(q, kp_ref[1],
+                                                     vp_ref[1], pt, pos))
     exact = bool(jnp.array_equal(ckp, kp_ref) & jnp.array_equal(cvp, vp_ref))
     _check("paged_fused", err <= ATTN_TOL and exact,
            f"max|err| {err:.3g}, KV scatter bitwise {exact}", t0)
-    del kp, vp, ckp, cvp, kp_ref, vp_ref
+    del kp, vp, kst, vst, ckp, cvp, kp_ref, vp_ref
 
     t0 = time.perf_counter()
     logits = jax.random.normal(ks[7], (B, cfg.vocab_size), jnp.float32)

@@ -61,8 +61,9 @@ def paged_decode_attention(q, k, v, page_table, pos, window, *,
                            logit_cap=0.0):
     """Paged serve-decode attention (pool already holds the new row).
 
-    q (B,H,hd); k/v pools (P,ps,K,hd); page_table (B,nb) i32; pos (B,)
-    i32; window i32 scalar (may be traced; <= 0 = global) -> (B,H,hd)."""
+    q (B,H,hd); k/v one layer's pools (P,ps,K,hd); page_table (B,nb)
+    i32; pos (B,) i32; window i32 scalar (may be traced; <= 0 = global)
+    -> (B,H,hd)."""
     return _pa.paged_decode_attention(
         q, k, v, page_table, pos, window, logit_cap=logit_cap,
         interpret=_interpret())
@@ -70,14 +71,15 @@ def paged_decode_attention(q, k, v, page_table, pos, window, *,
 
 @partial(jax.jit, static_argnames=("logit_cap",))
 def paged_decode_attention_fused(q, k, v, new_k, new_v, page_table, pos,
-                                 window, *, logit_cap=0.0):
+                                 window, layer, *, logit_cap=0.0):
     """Fused through-the-page-table KV scatter + paged decode attention.
 
-    Writes new_k/new_v (B,K,hd) into each row's boundary page at
-    pos[b] % ps inside the launch (aliased pools) and returns
-    (o, k_pool, v_pool)."""
+    k/v are the layer-stacked pools (L,P,ps,K,hd) and ``layer`` an i32
+    scalar (may be traced).  Writes new_k/new_v (B,K,hd) into each row's
+    boundary page of that layer at pos[b] % ps inside the launch (the
+    stacks aliased whole) and returns (o, k_stack, v_stack)."""
     return _pa.paged_decode_attention_fused(
-        q, k, v, new_k, new_v, page_table, pos, window,
+        q, k, v, new_k, new_v, page_table, pos, window, layer,
         logit_cap=logit_cap, interpret=_interpret())
 
 

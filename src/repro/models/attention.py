@@ -348,8 +348,13 @@ def attention_block(
     impl: str = "chunked",
     page_table=None,                   # paged serve: (B, nb) int32
     kv_write_mask=None,                # paged suffix prefill: (B, S) bool
+    layer=None,                        # pallas_paged: index into stacked cache
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """One attention op incl. projections, RoPE, cache handling."""
+    """One attention op incl. projections, RoPE, cache handling.
+
+    With ``impl="pallas_paged"``, ``cache`` holds the layer-stacked pools
+    (L, P, ps, K, hd), ``layer`` (int32, may be traced) picks this layer,
+    and the returned cache is the updated stacks."""
     B, S, D = x.shape
     src = kv_source if kv_source is not None else x
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -380,9 +385,6 @@ def attention_block(
             # there (always finite values, so masked softmax terms stay
             # exact zeros) and the per-row mask keeps it unreadable.
             pt = jnp.asarray(page_table, jnp.int32)
-            Pn, ps = cache["k"].shape[0], cache["k"].shape[1]
-            nbl = pt.shape[1]
-            trash = Pn - 1
             if impl == "pallas_paged":
                 if S != 1:
                     raise ValueError(
@@ -391,13 +393,18 @@ def attention_block(
                         "gather path (attn_impl='paged')")
                 win = jnp.asarray(0 if window is None else window,
                                   jnp.int32)
+                # cache holds the layer-stacked pools: the kernel writes
+                # layer `layer` in place and returns the whole stacks
                 o, ck, cv = kernel_ops.paged_decode_attention_fused(
                     q[:, 0], cache["k"], cache["v"],
                     k[:, 0].astype(cache["k"].dtype),
                     v[:, 0].astype(cache["v"].dtype),
-                    pt, cp, win, logit_cap=cfg.attn_softcap)
+                    pt, cp, win, layer, logit_cap=cfg.attn_softcap)
                 out = o[:, None]
             else:
+                Pn, ps = cache["k"].shape[0], cache["k"].shape[1]
+                nbl = pt.shape[1]
+                trash = Pn - 1
                 # jnp gather path (= the kernel's parity oracle): scatter
                 # this step's S rows through the page table, gather each
                 # row's pages into a linear (B, nb*ps) cache, attend with

@@ -206,7 +206,7 @@ def _maybe_remat(fn, cfg: ModelConfig, mode: str):
 
 def _decoder_layer(cfg: ModelConfig, p: Params, x, *, positions, window,
                    cache=None, cache_pos=None, return_kv=False, impl,
-                   page_table=None, kv_write_mask=None):
+                   page_table=None, kv_write_mask=None, layer=None):
     """Dense/MoE/VLM/SSM layer body. Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     x = constrain(x, _ACT)
@@ -219,7 +219,7 @@ def _decoder_layer(cfg: ModelConfig, p: Params, x, *, positions, window,
         cfg, subtree(p, "attn"), rms_norm(x, p["ln1/g"]),
         positions=positions, window=window, cache=cache,
         cache_pos=cache_pos, return_kv=return_kv, impl=impl,
-        page_table=page_table, kv_write_mask=kv_write_mask)
+        page_table=page_table, kv_write_mask=kv_write_mask, layer=layer)
     x = constrain(x + h, _ACT)
     z = rms_norm(x, p["ln2/g"])
     if cfg.is_moe:
@@ -320,15 +320,25 @@ def decoder_forward(
         # decode (page_table/kv_write_mask are layer-invariant: closed
         # over).  The stacked cache rides in the carry and each layer
         # writes its own slice back in place: scanned as xs -> ys it would
-        # need a second full-size cache buffer for the ys.
+        # need a second full-size cache buffer for the ys.  The paged
+        # Pallas kernel takes the whole stack and the layer index and
+        # aliases the stack whole, so nothing is sliced: XLA cannot alias
+        # a slice of the carry into the kernel, and would copy the
+        # layer's pool out and back on every layer of every tick.
+        whole = attn_impl == "pallas_paged" and page_table is not None
+
         def body(carry, xs):
             xc, ck, cv = carry
             lp, w, i = xs
+            c = {"k": ck, "v": cv} if whole else {"k": ck[i], "v": cv[i]}
             y, kv, _ = _decoder_layer(cfg, lp, xc, positions=positions,
-                                      window=w, cache={"k": ck[i], "v": cv[i]},
+                                      window=w, cache=c,
                                       cache_pos=cache_pos, impl=attn_impl,
                                       page_table=page_table,
-                                      kv_write_mask=kv_write_mask)
+                                      kv_write_mask=kv_write_mask,
+                                      layer=i if whole else None)
+            if whole:
+                return (y, kv["k"], kv["v"]), None
             ck = jax.lax.dynamic_update_index_in_dim(ck, kv["k"], i, 0)
             cv = jax.lax.dynamic_update_index_in_dim(cv, kv["v"], i, 0)
             return (y, ck, cv), None

@@ -126,22 +126,32 @@ def test_paged_kernel_matches_oracle(window):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_paged_fused_scatter_bitwise_and_attends_new_kv():
+@pytest.mark.parametrize("layers,layer", [(1, 0), (3, 0), (3, 2)],
+                         ids=["one_layer", "stack3_layer0", "stack3_layer2"])
+def test_paged_fused_scatter_bitwise_and_attends_new_kv(layers, layer):
+    """The fused kernel writes only layer ``layer``'s boundary rows of the
+    layer-stacked pools; every other layer keeps its bits."""
     q, k, v, pt, pos = _rand_paged(1, share=False)
     rng = np.random.default_rng(2)
     nk = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
     nv = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
-    o, k2, v2 = ops.paged_decode_attention_fused(q, k, v, nk, nv, pt, pos, 0)
+    # other layers: distinct random pools, so a write to the wrong layer
+    # or a stale block shows
+    others = rng.normal(size=(2, layers) + k.shape).astype(np.float32)
+    ks, vs = others[0], others[1]
+    ks[layer], vs[layer] = np.asarray(k), np.asarray(v)
+    o, k2, v2 = ops.paged_decode_attention_fused(
+        q, jnp.asarray(ks), jnp.asarray(vs), nk, nv, pt, pos, 0, layer)
     ps = 8
-    ek, ev = np.array(k), np.array(v)
+    ek, ev = ks.copy(), vs.copy()
     for b in range(3):
         page = int(pt[b, int(pos[b]) // ps])
-        ek[page, int(pos[b]) % ps] = np.asarray(nk[b])
-        ev[page, int(pos[b]) % ps] = np.asarray(nv[b])
+        ek[layer, page, int(pos[b]) % ps] = np.asarray(nk[b])
+        ev[layer, page, int(pos[b]) % ps] = np.asarray(nv[b])
     np.testing.assert_array_equal(np.asarray(k2), ek)
     np.testing.assert_array_equal(np.asarray(v2), ev)
-    want = ref.paged_decode_attention_ref(q, jnp.asarray(ek), jnp.asarray(ev),
-                                          pt, pos)
+    want = ref.paged_decode_attention_ref(
+        q, jnp.asarray(ek[layer]), jnp.asarray(ev[layer]), pt, pos)
     np.testing.assert_allclose(np.asarray(o), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 

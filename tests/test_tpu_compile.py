@@ -13,18 +13,23 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this module.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import cache_sim, decode_attention, paged_attention
+from repro.configs import get_config
+from repro.kernels import cache_sim, decode_attention, ops, paged_attention
 from repro.kernels import sampling
+from repro.models import build_model
 
 SLOTS, HEADS, KV_HEADS, HEAD_DIM = 8, 32, 32, 96
 MAX_LEN, VOCAB, PAGE = 2048, 32064, 16
+LAYERS = 3
 
 
 @pytest.fixture(scope="module")
@@ -62,13 +67,18 @@ def _dense(fused):
 
 
 def _paged(fused):
+    """Operands of the paged kernel: the fused variant takes the
+    layer-stacked pools and a layer index, the plain one a layer's pool."""
     bf = jnp.bfloat16
     nb = MAX_LEN // PAGE
     pool = (SLOTS * nb + 1, PAGE, KV_HEADS, HEAD_DIM)
+    if fused:
+        pool = (LAYERS,) + pool
     rows = [((SLOTS, KV_HEADS, HEAD_DIM), bf)] * 2 if fused else []
+    layer = [((), jnp.int32)] if fused else []
     return [((SLOTS, HEADS, HEAD_DIM), bf), (pool, bf), (pool, bf), *rows,
             ((SLOTS, nb), jnp.int32), ((SLOTS,), jnp.int32),
-            ((), jnp.int32)]
+            ((), jnp.int32), *layer]
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["plain", "fused"])
@@ -83,6 +93,71 @@ def test_paged_decode_attention_compiles(one_chip, fused):
     fn = (paged_attention.paged_decode_attention_fused if fused
           else paged_attention.paged_decode_attention)
     _compile(fn, one_chip, *_paged(fused))
+
+
+def _while_computations(hlo):
+    """Instruction lines of every computation a while body runs, fused
+    computations included, keyed by computation name."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    todo = re.findall(r"body=%([\w.\-]+)", hlo)
+    seen = {}
+    while todo:
+        n = todo.pop()
+        if n in seen:
+            continue
+        seen[n] = comps[n]
+        for line in comps[n]:
+            todo += re.findall(
+                r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line)
+    return seen
+
+
+def test_paged_decode_scan_copies_no_layer_pool(one_chip, monkeypatch):
+    """The model's pallas_paged decode step at phi3 widths (8 slots x
+    1024 positions in 16-row pages: 513 pages with the trash page) hands
+    the kernel the layer-stacked pools, so no slice, write-back or copy
+    of one layer's pool runs inside the layer scan."""
+    # the backend is the CPU here: compile the kernel, not its interpreter
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    max_len = 1024
+    nb = max_len // PAGE
+    cfg = dataclasses.replace(get_config("phi3-mini-3.8b"), num_layers=2)
+    model = build_model(cfg, max_seq=max_len)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(
+        lambda: model.init_paged_cache(SLOTS * nb + 1, PAGE)))
+
+    def step(params, cache, tokens, pos, pt):
+        return model.decode_step(params, cache, {"tokens": tokens}, pos,
+                                 attn_impl="pallas_paged", page_table=pt)
+
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((SLOTS, 1), (SLOTS,), (SLOTS, nb))]
+    hlo = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, *ints).compile().as_text()
+    bodies = _while_computations(hlo)
+    assert any("tpu_custom_call" in line
+               for lines in bodies.values() for line in lines)
+    layer_pool = re.compile(
+        rf"= bf16\[(1,)?{SLOTS * nb + 1},{PAGE},{KV_HEADS},{HEAD_DIM}\]"
+        r"\S* (dynamic-slice|dynamic-update-slice|copy|copy-done)\(")
+    copies = [line.strip()[:120] for lines in bodies.values()
+              for line in lines if layer_pool.search(line)]
+    assert not copies, copies
 
 
 def test_fused_sample_compiles_at_unaligned_vocab(one_chip):
