@@ -139,8 +139,12 @@ class Model:
     def forward(self, params: Params, batch: Dict[str, jax.Array], *,
                 mode: str = "train", cache: Optional[Params] = None,
                 cache_pos=None, attn_impl: str = "chunked",
-                page_table=None, kv_write_mask=None):
+                page_table=None, kv_write_mask=None, last_index=None):
         cfg = self.cfg
+        if last_index is not None and (
+                mode != "decode" or cfg.family in ("encdec", "hybrid", "ssm")):
+            raise ValueError("last_index picks the head rows of a "
+                             "dense/moe/vlm decode step")
         if cfg.family == "encdec":
             if page_table is not None:
                 raise ValueError("paged KV serving requires a dense/moe/vlm "
@@ -160,7 +164,7 @@ class Model:
             cfg, params, batch["tokens"], mode=mode, cache=cache,
             cache_pos=cache_pos, vision_embeds=batch.get("vision_embeds"),
             attn_impl=attn_impl, page_table=page_table,
-            kv_write_mask=kv_write_mask)
+            kv_write_mask=kv_write_mask, last_index=last_index)
 
     def loss(self, params: Params, batch: Dict[str, jax.Array], *,
              attn_impl: str = "chunked") -> jax.Array:
@@ -177,7 +181,7 @@ class Model:
 
     def decode_step(self, params: Params, cache: Params, batch:
                     Dict[str, jax.Array], pos, *, attn_impl: str = "chunked",
-                    page_table=None, kv_write_mask=None):
+                    page_table=None, kv_write_mask=None, last_index=None):
         """One decode step. ``pos`` is a scalar write position for the whole
         batch, or a (B,) int32 vector of per-row positions (continuous
         batching: every serve slot decodes at its own depth — or, for
@@ -187,11 +191,12 @@ class Model:
         With ``page_table`` (B, nb) the cache is the paged pool and
         ``pos`` each row's first write position; tokens (B, S) with
         S > 1 is the paged suffix prefill (writes masked by
-        ``kv_write_mask``; see DESIGN.md §15)."""
+        ``kv_write_mask``; see DESIGN.md §15).  ``last_index`` (B,)
+        unembeds only that position of each row: logits (B, 1, V)."""
         logits, new_cache, _ = self.forward(
             params, batch, mode="decode", cache=cache, cache_pos=pos,
             attn_impl=attn_impl, page_table=page_table,
-            kv_write_mask=kv_write_mask)
+            kv_write_mask=kv_write_mask, last_index=last_index)
         return logits, new_cache
 
     # ---- serve capability metadata (DESIGN.md §17) ----------------------

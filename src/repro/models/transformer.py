@@ -247,6 +247,15 @@ def _unembed(cfg: ModelConfig, params: Params, x):
     return softcap(logits.astype(jnp.float32), cfg.final_softcap)
 
 
+def _rows_at(x, last_index):
+    """(B, S, D) -> (B, 1, D) at position ``last_index[b]`` of each row;
+    ``x`` itself when no index is given."""
+    if last_index is None:
+        return x
+    idx = jnp.asarray(last_index, jnp.int32)[:, None, None]
+    return jnp.take_along_axis(x, idx, axis=1)
+
+
 def decoder_forward(
     cfg: ModelConfig,
     params: Params,
@@ -259,6 +268,7 @@ def decoder_forward(
     attn_impl: str = "chunked",
     page_table=None,                    # paged serve: (B, nb) int32
     kv_write_mask=None,                 # paged suffix prefill: (B, S) bool
+    last_index=None,                    # decode: (B,) int32, head rows
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """Returns (logits, new_cache, aux_loss).
 
@@ -277,6 +287,11 @@ def decoder_forward(
     s``, writes masked by ``kv_write_mask``), ``S == 1`` the paged
     decode tick, ``attn_impl="pallas_paged"`` its Pallas kernel
     (DESIGN.md §15).
+
+    With ``last_index`` in decode mode, only position ``last_index[b]``
+    of row b goes through the final norm and the output head, and the
+    logits are (B, 1, V): the paged suffix prefill samples each row's
+    first token from its last suffix position and needs no other.
     """
     B, S = tokens.shape
     x = _embed(cfg, params, tokens, vision_embeds)
@@ -345,7 +360,8 @@ def decoder_forward(
         layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
         (x, ck, cv), _ = jax.lax.scan(
             body, (x, cache["k"], cache["v"]), (blocks, windows, layers))
-        return _unembed(cfg, params, x), {"k": ck, "v": cv}, aux_total
+        return (_unembed(cfg, params, _rows_at(x, last_index)),
+                {"k": ck, "v": cv}, aux_total)
 
     # unrolled homogeneous stack
     new_cache: Dict[str, jax.Array] = {}
@@ -370,7 +386,8 @@ def decoder_forward(
     out_cache = None
     if new_cache:
         out_cache = {k: jnp.stack(v) for k, v in new_cache.items()}
-    return _unembed(cfg, params, x), out_cache, aux_total
+    return (_unembed(cfg, params, _rows_at(x, last_index)), out_cache,
+            aux_total)
 
 
 def _ssm_forward(cfg, params, x, *, mode, cache):

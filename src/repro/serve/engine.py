@@ -52,6 +52,11 @@ from repro.serve.resilience import (DONE, FAILED, PENDING, QUEUED, RUNNING,
                                     SHED, TERMINAL_STATES, TIMED_OUT,
                                     ShedPolicy, WindowWatchdog)
 
+# rows of the paged suffix prefill: one admission prefills at most this
+# many requests (the rest stay queued), so its program, its attention
+# transient and its work scale with the admitted, not with the slots
+PREFILL_ROWS = 8
+
 
 class UnsupportedFamilyError(ValueError):
     """Raised at ENGINE CONSTRUCTION for a model family the engine cannot
@@ -680,17 +685,19 @@ class Engine:
         return end
 
     def _trace_admit(self, leaf: str, uids: List[int], queued: int,
-                     decoding: int, deferred: int = 0,
-                     shed: int = 0) -> None:
+                     decoding: int, deferred: int = 0, shed: int = 0,
+                     capped: int = 0) -> None:
         """Close an admission: the ``leaf`` span of the bookkeeping after
         the prefill, then the ``admit`` span over every admission leaf
         since step entry.  ``decoding`` counts the slots already running
-        a request, whose decode waits on this admission."""
+        a request, whose decode waits on this admission; ``capped`` the
+        requests that a free slot could have taken but the prefill's row
+        limit left queued."""
         t = self._leaf(leaf, leaf)
         self.tracer.span("admit", "admit", self._t_step, t, args={
             "tick": self.ticks, "queued": queued, "admitted": len(uids),
             "deferred": deferred, "shed": shed, "uids": uids,
-            "decoding": decoding})
+            "decoding": decoding, "capped": capped})
 
     # ---- admission ------------------------------------------------------
     def submit(self, req: Request) -> bool:
@@ -1097,11 +1104,13 @@ class PagedEngine(Engine):
     suffix starts mid-page, and reserves the slot's FULL page span
     ``ceil(min(L + max_new, max_len) / page_size)`` up front — decode
     never allocates mid-flight.  Only the unshared suffix runs through
-    the (batched, masked) paged prefill; finished prompts insert into
-    the tree so later requests can share them.  When the pool runs
-    short, LRU tree leaves evict; if still short, admission defers to a
-    later sync point (deadlock-free: a lone request needs at most
-    ``nb`` pages and full eviction frees everything).
+    the (batched, masked) paged prefill, whose rows are the admitted
+    requests alone (at most ``PREFILL_ROWS`` a step; the rest stay
+    queued); finished prompts insert into the tree so later requests can
+    share them.  When the pool runs short, LRU tree leaves evict; if
+    still short, admission defers to a later sync point (deadlock-free:
+    a lone request needs at most ``nb`` pages and full eviction frees
+    everything).
 
     Decode runs the same fused K-tick window as ``Engine`` with the page
     table as an extra operand: ``attn_impl="xla"`` takes the jnp
@@ -1148,6 +1157,7 @@ class PagedEngine(Engine):
                 f"num_pages {self.num_pages} cannot hold one full-length "
                 f"request ({self.nb} pages)")
         self.trash = self.num_pages
+        self.rows = min(slots, PREFILL_ROWS)
         super().__init__(model, params, slots=slots, max_len=max_len, **kw)
         # decode through the paged branch of attention_block: plain jnp
         # gather under "xla", fused Pallas kernel under "pallas_paged"
@@ -1324,7 +1334,7 @@ class PagedEngine(Engine):
         pairs = []
         deferred: List[Request] = []
         shed = 0
-        while free and self._queue:
+        while free and self._queue and len(pairs) < self.rows:
             r = self._queue.popleft()
             plan = self._plan(r)
             if plan is None:
@@ -1343,6 +1353,7 @@ class PagedEngine(Engine):
                     deferred.append(r)
                 continue
             pairs.append((free.pop(0), r, plan))
+        capped = min(len(free), len(self._queue))
         for r in reversed(deferred):
             self._queue.appendleft(r)
         if not pairs:
@@ -1370,30 +1381,33 @@ class PagedEngine(Engine):
             self._pt_host[s] = row
         self._pt_dev = jnp.asarray(self._pt_host)
         self._pt_dirty = False
-        # batched suffix prefill (only unshared tokens run the model)
+        # batched suffix prefill (only unshared tokens run the model), one
+        # row per admitted request; padding rows write only to TRASH and
+        # scatter into no slot (index ``slots`` is dropped)
+        R = self.rows
         S = min(self.max_len,
                 _next_pow2(max(p["L"] - p["matched"] for _, _, p in pairs)))
-        tokens = np.zeros((self.slots, S), np.int32)
-        mask = np.zeros((self.slots, S), bool)
-        starts = np.zeros(self.slots, np.int32)
-        suf_lens = np.zeros(self.slots, np.int32)
-        full_lens = np.zeros(self.slots, np.int32)
-        admit = np.zeros(self.slots, bool)
-        max_new = np.ones(self.slots, np.int32)
-        temps = np.zeros(self.slots, np.float32)
-        for s, r, p in pairs:
+        tokens = np.zeros((R, S), np.int32)
+        mask = np.zeros((R, S), bool)
+        slot = np.full(R, self.slots, np.int32)
+        starts = np.zeros(R, np.int32)
+        suf_lens = np.zeros(R, np.int32)
+        full_lens = np.zeros(R, np.int32)
+        max_new = np.ones(R, np.int32)
+        temps = np.zeros(R, np.float32)
+        for i, (s, r, p) in enumerate(pairs):
             suf = p["prompt"][p["matched"]:]
-            tokens[s, :len(suf)] = suf
-            mask[s, :len(suf)] = True
-            starts[s] = p["matched"]
-            suf_lens[s] = len(suf)
-            full_lens[s] = p["L"]
-            admit[s] = True
-            max_new[s] = r.max_new_tokens - len(r.output)
-            temps[s] = r.temperature
+            tokens[i, :len(suf)] = suf
+            mask[i, :len(suf)] = True
+            slot[i] = s
+            starts[i] = p["matched"]
+            suf_lens[i] = len(suf)
+            full_lens[i] = p["L"]
+            max_new[i] = r.max_new_tokens - len(r.output)
+            temps[i] = r.temperature
         args = (self.params, self.cache, self._state, jnp.asarray(tokens),
-                self._pt_dev, jnp.asarray(starts), jnp.asarray(suf_lens),
-                jnp.asarray(full_lens), jnp.asarray(admit),
+                self._pt_dev, jnp.asarray(slot), jnp.asarray(starts),
+                jnp.asarray(suf_lens), jnp.asarray(full_lens),
                 jnp.asarray(max_new), jnp.asarray(temps), self.key,
                 jnp.asarray(mask))
         if S not in self._traffic["prefill"]:
@@ -1412,30 +1426,30 @@ class PagedEngine(Engine):
             self._leaf(
                 f"prefill_chunk S={S}", "prefill", end=now,
                 args={"tick": self.ticks, "admitted": len(pairs),
-                      "padded_len": S,
+                      "rows": R, "padded_len": S,
                       "suffix_tokens": int(suf_lens.sum()),
                       "shared_tokens": int((full_lens - suf_lens).sum()),
                       "uids": [r.uid for _, r, _ in pairs]})
         if self.charge_prefill_ticks:
             self.ticks += -(-int(suf_lens.sum()) // self.slots)
         bad0: Dict[int, int] = {}
-        for s, r, p in pairs:
+        for i, (s, r, p) in enumerate(pairs):
             self.slot_req[s] = r
             r._mark_admitted(self.ticks, now, t_admit)
-            if self.health_check and not ok0[s]:
+            if self.health_check and not ok0[i]:
                 # poisoned prefill (a shared or recycled page carried
                 # corrupt KV): discard t0 and requeue via quarantine —
                 # the tree flush + page scrub below cleans the source
                 bad0[s] = 0
                 continue
-            r.output.append(int(t0[s]))
+            r.output.append(int(t0[i]))
             # register the full effective prompt's pages so later prompts
             # share them (the tree takes its own references; safe even if
             # this slot keeps decoding into the boundary page at rows
             # >= L, which the tree never vouches for)
             self.stats["inserted_nodes"] += self.tree.insert(
                 p["prompt"], p["pages"][:pages_for(p["L"], self.page_size)])
-            if done0[s]:
+            if done0[i]:
                 r._mark_done(self.ticks, now)
                 self._release_slot(s)
                 self.slot_req[s] = None
@@ -1444,39 +1458,40 @@ class PagedEngine(Engine):
             self._quarantine(bad0, now)
         if self.tracer is not None:
             self._trace_admit("tree", [r.uid for _, r, _ in pairs], queued,
-                              decoding, len(deferred), shed)
+                              decoding, len(deferred), shed, capped)
         return len(pairs)
 
-    def _prefill_prog(self, params, cache, state, tokens, pt, starts,
-                      suf_lens, full_lens, admit, max_new, temps_in, key,
-                      mask):
-        """Batched paged SUFFIX prefill: decode-mode forward with S > 1
-        tokens per row starting at each row's ``starts`` (= matched
-        prefix length).  ``mask`` routes every non-suffix write to the
-        TRASH page, so rows mid-decode and the shared prefix pages stay
-        bit-identical; per-row causal masking makes the suffix KV
-        independent of other rows.  Samples t0 from each admitted row's
-        last suffix position."""
+    def _prefill_prog(self, params, cache, state, tokens, pt, slot, starts,
+                      suf_lens, full_lens, max_new, temps_in, key, mask):
+        """Batched paged SUFFIX prefill of the admitted requests, one row
+        each: decode-mode forward with S > 1 tokens per row starting at
+        its ``starts`` (= matched prefix length), through row ``slot`` of
+        the whole page table ``pt`` (gathered here, so the one upload at
+        admission also serves the decode window).  ``mask`` routes every
+        non-suffix write to the TRASH page, so rows mid-decode and the
+        shared prefix pages stay bit-identical; per-row causal masking makes the suffix KV
+        independent of other rows.  Only each row's last suffix position
+        reaches the output head; t0 is sampled there and, with the row's
+        state, scattered into slot ``slot`` (padding rows carry
+        ``slots``, which the scatter drops)."""
         S = tokens.shape[1]
+        rows_pt = jnp.take(pt, slot, axis=0, mode="fill",
+                           fill_value=self.trash)
         logits, cache = self.model.decode_step(
             params, cache, {"tokens": tokens}, starts, attn_impl="xla",
-            page_table=pt, kv_write_mask=mask)
-        idx = jnp.clip(suf_lens - 1, 0, S - 1)
-        last_lg = jnp.take_along_axis(
-            logits, idx[:, None, None], axis=1)[:, 0].astype(jnp.float32)
+            page_table=rows_pt, kv_write_mask=mask,
+            last_index=jnp.clip(suf_lens - 1, 0, S - 1))
+        last_lg = logits[:, 0].astype(jnp.float32)
         ok0 = jnp.isfinite(last_lg).all(axis=-1)
         key, sub = jax.random.split(key)
         t0 = self._sample_batch(last_lg, temps_in, sub)
         done0 = (max_new - 1 <= 0) | (full_lens >= self.max_len)
         if self.eos_id is not None:
             done0 = done0 | (t0 == self.eos_id)
-        state = {
-            "last": jnp.where(admit, t0, state["last"]),
-            "pos": jnp.where(admit, full_lens, state["pos"]),
-            "active": jnp.where(admit, ~done0, state["active"]),
-            "remaining": jnp.where(admit, max_new - 1, state["remaining"]),
-            "temps": jnp.where(admit, temps_in, state["temps"]),
-        }
+        new = {"last": t0, "pos": full_lens, "active": ~done0,
+               "remaining": max_new - 1, "temps": temps_in}
+        state = {n: state[n].at[slot].set(new[n], mode="drop")
+                 for n in state}
         return cache, state, key, t0, done0, ok0
 
     # ---- serve-mode NVM verdicts ---------------------------------------

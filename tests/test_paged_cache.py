@@ -217,6 +217,49 @@ def test_fused_sample_tracks_softmax_distribution():
     np.testing.assert_allclose(freq, want, atol=0.05)
 
 
+# --- last-position head of the suffix prefill -------------------------------
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas_paged"])
+def test_last_position_head_is_the_row_of_every_position(mp, attn_impl):
+    """With ``last_index`` only each row's last suffix position reaches the
+    final norm and the head: its logits, the pools it leaves and the t0
+    sampled from it are bitwise those of unembedding every position and
+    taking the row.  ``xla`` runs the S > 1 suffix prefill the engine
+    runs; ``pallas_paged`` the one-token tick, whose only position is
+    the last."""
+    model, params = mp
+    B, nb = 3, MAX_LEN // PS
+    S = 8 if attn_impl == "xla" else 1
+    rng = np.random.default_rng(5)
+    pool = model.init_paged_cache(B * nb + 1, PS)
+    cache = {k: jnp.asarray(rng.normal(size=v.shape), v.dtype)
+             for k, v in pool.items()}
+    pt = jnp.asarray(np.arange(B * nb).reshape(B, nb), jnp.int32)
+    starts = jnp.asarray([0, 5, 13], jnp.int32)
+    lens = np.minimum([S, 5, 3], S)
+    tokens = jnp.asarray(rng.integers(0, 512, (B, S)), jnp.int32)
+    kw = dict(attn_impl=attn_impl, page_table=pt,
+              kv_write_mask=jnp.asarray(np.arange(S) < lens[:, None]))
+    full, want_cache = model.decode_step(
+        params, cache, {"tokens": tokens}, starts, **kw)
+    head, got_cache = model.decode_step(
+        params, cache, {"tokens": tokens}, starts,
+        last_index=jnp.asarray(lens - 1, jnp.int32), **kw)
+    want = np.take_along_axis(np.asarray(full), (lens - 1)[:, None, None],
+                              axis=1)
+    assert head.shape == (B, 1, full.shape[-1])
+    np.testing.assert_array_equal(np.asarray(head), want)
+    for k in want_cache:
+        np.testing.assert_array_equal(np.asarray(got_cache[k]),
+                                      np.asarray(want_cache[k]))
+    greedy = jnp.zeros(B, jnp.float32)
+    key = jax.random.PRNGKey(3)
+    np.testing.assert_array_equal(
+        np.asarray(ops.fused_sample(head[:, 0], greedy, key)),
+        np.asarray(ops.fused_sample(jnp.asarray(want[:, 0]), greedy, key)))
+
+
 # --- engine parity ----------------------------------------------------------
 
 

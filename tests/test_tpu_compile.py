@@ -1,6 +1,9 @@
 """Compile the serve path's Pallas kernels for a TPU v5e that is described,
-not attached, at phi3-mini widths (32 q/kv heads of width 96, vocab 32064,
-8 slots, 2048 positions).
+not attached, at the widths of the benchmark's configurations: phi3-mini
+(32 q/kv heads of width 96, vocab 32064, 8 slots, 2048 positions) and
+qwen2-7b (28 query heads over 4 kv heads of width 128, GQA group 7,
+vocab 152064, 48 slots, 2560 positions); and the paged engine's decode
+window and widest prefill at each cell's sizes, within one chip's memory.
 
 Interpret mode on the CPU cannot see what the chip's compiler refuses:
 blocks that break the (8, 128) tiling rule, more VMEM or SMEM than a
@@ -26,10 +29,19 @@ from repro.configs import get_config
 from repro.kernels import cache_sim, decode_attention, ops, paged_attention
 from repro.kernels import sampling
 from repro.models import build_model
+from repro.serve import PagedEngine
 
 SLOTS, HEADS, KV_HEADS, HEAD_DIM = 8, 32, 32, 96
 MAX_LEN, VOCAB, PAGE = 2048, 32064, 16
 LAYERS = 3
+# (slots, query heads, kv heads, head width, positions, vocabulary)
+WIDTHS = {"phi3": (SLOTS, HEADS, KV_HEADS, HEAD_DIM, MAX_LEN, VOCAB),
+          "qwen2h": (48, 28, 4, 128, 2560, 152064)}
+# each cell's engine: arch, layers, slots, positions, widest prefill
+CELLS = {"phi3": ("phi3-mini-3.8b", 32, 8, 1024, 1024),
+         "qwen2h": ("qwen2-7b", 14, 48, 2560, 512)}
+# what one v5e program may hold: the chip's 16 GiB less the runtime's
+CHIP_BYTES = 15.75 * 2 ** 30
 
 
 @pytest.fixture(scope="module")
@@ -66,18 +78,19 @@ def _dense(fused):
             *rows, ((SLOTS,), jnp.int32), ((), jnp.int32)]
 
 
-def _paged(fused):
+def _paged(fused, widths):
     """Operands of the paged kernel: the fused variant takes the
     layer-stacked pools and a layer index, the plain one a layer's pool."""
     bf = jnp.bfloat16
-    nb = MAX_LEN // PAGE
-    pool = (SLOTS * nb + 1, PAGE, KV_HEADS, HEAD_DIM)
+    slots, heads, kv_heads, head_dim, max_len, _ = WIDTHS[widths]
+    nb = max_len // PAGE
+    pool = (slots * nb + 1, PAGE, kv_heads, head_dim)
     if fused:
         pool = (LAYERS,) + pool
-    rows = [((SLOTS, KV_HEADS, HEAD_DIM), bf)] * 2 if fused else []
+    rows = [((slots, kv_heads, head_dim), bf)] * 2 if fused else []
     layer = [((), jnp.int32)] if fused else []
-    return [((SLOTS, HEADS, HEAD_DIM), bf), (pool, bf), (pool, bf), *rows,
-            ((SLOTS, nb), jnp.int32), ((SLOTS,), jnp.int32),
+    return [((slots, heads, head_dim), bf), (pool, bf), (pool, bf), *rows,
+            ((slots, nb), jnp.int32), ((slots,), jnp.int32),
             ((), jnp.int32), *layer]
 
 
@@ -88,11 +101,13 @@ def test_decode_attention_compiles(one_chip, fused):
     _compile(fn, one_chip, *_dense(fused))
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["plain", "fused"])
-def test_paged_decode_attention_compiles(one_chip, fused):
+@pytest.mark.parametrize("fused,widths", [
+    (False, "phi3"), (True, "phi3"), (False, "qwen2h"), (True, "qwen2h"),
+], ids=["plain", "fused", "plain-qwen2h", "fused-qwen2h"])
+def test_paged_decode_attention_compiles(one_chip, fused, widths):
     fn = (paged_attention.paged_decode_attention_fused if fused
           else paged_attention.paged_decode_attention)
-    _compile(fn, one_chip, *_paged(fused))
+    _compile(fn, one_chip, *_paged(fused, widths))
 
 
 def _while_computations(hlo):
@@ -160,9 +175,63 @@ def test_paged_decode_scan_copies_no_layer_pool(one_chip, monkeypatch):
     assert not copies, copies
 
 
-def test_fused_sample_compiles_at_unaligned_vocab(one_chip):
-    _compile(sampling.fused_sample, one_chip, ((SLOTS, VOCAB), jnp.float32),
-             ((SLOTS,), jnp.float32), ((2,), jnp.uint32))
+@pytest.mark.parametrize("widths", list(WIDTHS))
+def test_fused_sample_compiles_at_unaligned_vocab(one_chip, widths):
+    slots, vocab = WIDTHS[widths][0], WIDTHS[widths][-1]
+    _compile(sampling.fused_sample, one_chip, ((slots, vocab), jnp.float32),
+             ((slots,), jnp.float32), ((2,), jnp.uint32))
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_serve_programs_fit_one_chip(one_chip, monkeypatch, cell):
+    """The paged engine's 8-tick decode window and its widest suffix
+    prefill (``PREFILL_ROWS`` rows), at the cell's depth, slots and
+    positions, each hold their arguments and temporaries in one chip's
+    memory; and the compiled KV pool is no more than 1.5 x its logical
+    bytes (the (page, kv heads, head width) blocks are what the layout
+    may pad)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    # no pool or parameters are made here: the programs see shapes only
+    monkeypatch.setattr(PagedEngine, "reset", lambda self, seed=None: None)
+    arch, layers, slots, max_len, widest = CELLS[cell]
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    model = build_model(cfg, max_seq=max_len)
+    eng = PagedEngine(model, None, slots=slots, max_len=max_len,
+                      page_size=PAGE, eos_id=None, ticks_per_sync=8,
+                      record_traffic=False, attn_impl="pallas_paged",
+                      sample_impl="pallas")
+    nb, R = max_len // PAGE, eng.rows
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = shaped(jax.eval_shape(
+        lambda: model.init_paged_cache(slots * nb + 1, PAGE)))
+    i32, f32 = jnp.int32, jnp.float32
+    state = {"last": arr(i32, slots), "pos": arr(i32, slots),
+             "active": arr(bool, slots), "remaining": arr(i32, slots),
+             "temps": arr(f32, slots)}
+    key = arr(jnp.uint32, 2)
+    window = eng._window_jit.lower(
+        params, cache, state, key, arr(bool, slots),
+        arr(i32, slots, nb)).compile()
+    prefill = eng._prefill_jit.lower(
+        params, cache, state, arr(i32, R, widest), arr(i32, slots, nb),
+        *[arr(i32, R)] * 5, arr(f32, R), key,
+        arr(bool, R, widest)).compile()
+    for prog in (window, prefill):
+        ma = prog.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+            < CHIP_BYTES
+    logical = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    # the window returns the donated pool and the slot state
+    assert window.memory_analysis().output_size_in_bytes \
+        <= 1.5 * logical + 2 ** 20
 
 
 def test_cache_sim_ladder_compiles_for_long_traces(one_chip):
