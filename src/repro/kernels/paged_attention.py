@@ -8,34 +8,41 @@ table row ``pt (B, nb)``.  Requests with a common prompt prefix map the
 *same* physical pages, so the pool holds one copy of every shared
 prefix.
 
-The page table rides in as a scalar-prefetch operand beside ``pos``,
-``window`` and the layer index, and the KV block-fetch index map
-dereferences it:
+The pools stay in HBM (``pl.ANY``) and the kernel moves pages itself.
+The page table (flattened), ``pos``, ``window`` and the layer index are
+scalar-prefetch operands; the grid is ``(B,)``, one step per row, and
+each row walks only its live pages ``[lo, tb]`` (``_block_bounds``:
+``tb = pos // ps``, ``lo`` the first page inside a local window):
 
-  grid (B, nb), j innermost (sequential, carries scratch);
-  kv index map   (layer, pt[b, clip(j, lo_b, tb_b)], 0, 0, 0)
+  blocks of ``ppb`` consecutive logical pages from ``lo``, each page one
+  async copy ``pool[layer, pt[b, j]] -> VMEM`` through the page table,
+  double-buffered so block ``c + 1`` is in flight while block ``c`` runs
+  the online softmax (m, l, acc in f32) over its ``ppb * ps`` keys.
 
-with ``tb_b = pos[b] // ps`` the row's last live logical page and
-``lo_b`` the first page inside its local window — DMA is still clamped
-to each slot's own depth exactly as in the dense kernel, and Pallas
-elides refetches when consecutive grid steps map the same physical
-page.  Logical key positions are reconstructed in-kernel as
-``j * ps + iota`` (valid because accumulation is gated on
-``lo <= j <= tb`` where the clamp is the identity).
+A block's scores are one matmul of the H query heads against all its
+(key, kv head) rows, masked to each head's own kv head: K times the MXU
+work of a per-head product, but the block is used in its DMA layout
+with no transpose.
 
-The FUSED variant scatters the new token's K/V row through the page
-table into the *boundary page* (the page holding ``pos[b]``) of layer
-``layer`` inside the same launch.  It takes the whole layer stack and
-aliases it whole to its outputs, so the model's decode scan hands it the
-stacks it carries and the traced layer index and takes the returned
-stacks as its next carry.  Handed one layer's slice instead, XLA would
-materialise the slice and its write-back — two copies of a whole layer
-pool per layer and tick, for the one row the kernel writes.
-Preconditions the engine maintains (DESIGN.md §15): each live row's
-boundary page is private to that row (copy-on-write at admission
-guarantees it), so the in-place row injection never races; pages shared
-read-only are written back bit-identically, and blocks the grid never
-maps (other layers, unmapped pages of this layer) keep their input bits.
+``ppb`` follows from a page's bytes (a K+V block of at most
+``_BLOCK_BYTES``), never from a per-model knob.  Pages past ``tb`` in
+the last block are not copied; the key mask hides their stale VMEM and
+their V rows are zeroed.  A row whose boundary page is the TRASH page
+(the pool's last page, ``init_paged_cache``; an idle slot's table row is
+all TRASH) walks nothing and reads no page.
+
+The FUSED variant writes the new token's K/V row ``(K, hd)`` straight
+from VMEM into ``(layer, pt[b, tb], pos[b] % ps)`` of the layer stacks,
+which it aliases whole to its outputs, and injects the same row into
+the VMEM copy of the boundary page, so this call attends it whatever
+the order of the write and the read.  That one row per row is all the
+kernel writes: every other bit of both stacks (other layers, the rest of
+the boundary page, every page read) keeps its input bits, so the decode
+scan hands it the stacks it carries and takes the returned stacks as its
+next carry, with no copy of a layer's pool.  Preconditions the engine
+maintains (DESIGN.md §15): each live row's boundary page is private to
+that row (copy-on-write at admission), so the row write never races
+another row's read; an idle row's write lands in the TRASH page.
 
 Layouts: q (B, H, hd); k/v pools (L, P, ps, K, hd) (one layer's
 ``(P, ps, K, hd)`` for the unfused variant); page_table (B, nb) int32;
@@ -52,129 +59,195 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import NEG_INF, _block_bounds
+from repro.kernels.decode_attention import (NEG_INF, VMEM_LIMIT_BYTES,
+                                            _block_bounds)
+
+# Logical bytes of one K+V page block: enough keys a DMA block to
+# amortise the per-block loop and DMA waits, small enough that the two
+# buffers and their f32 copies sit well inside the scoped VMEM.
+_BLOCK_BYTES = 512 << 10
 
 
-def _paged_kernel(pt_ref, pos_ref, win_ref, layer_ref, q_ref, k_ref, v_ref,
-                  *rest, ps: int, group: int, logit_cap: float, scale: float,
-                  fused: bool):
+def pages_per_block(nb: int, ps: int, kv_heads: int, head_dim: int,
+                    dtype) -> int:
+    """Pages per DMA block: the most whose K+V bytes fit ``_BLOCK_BYTES``,
+    at least 1 and at most the table's ``nb``."""
+    page = ps * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, min(nb, _BLOCK_BYTES // (2 * page)))
+
+
+def _paged_kernel(pt_ref, pos_ref, win_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                  *rest, ps: int, nb: int, ppb: int, trash: int, group: int,
+                  hd: int, logit_cap: float, scale: float, fused: bool):
     if fused:
-        nk_ref, nv_ref, o_ref, ck_ref, cv_ref, m_scr, l_scr, acc_scr = rest
+        (nk_ref, nv_ref, o_ref, ck_hbm, cv_hbm,
+         kbuf, vbuf, sems, wsems) = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, kbuf, vbuf, sems = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
-
     pos_b = pos_ref[b]
     win = win_ref[0]
+    layer = layer_ref[0]
     lo, tb = _block_bounds(pos_b, win, ps)
-    jc = jnp.clip(j, lo, tb)          # logical page actually mapped
+    pt_row = b * nb                    # row b's offset in the flat table
+    boundary = pt_ref[pt_row + tb]
+    n_blocks = jnp.where(boundary == trash, 0, (tb - lo) // ppb + 1)
+    K, lanes = kbuf.shape[-2:]
+    T = ppb * ps
+    tile = (slice(None), slice(None), pl.ds(0, lanes))  # (ps, K, lanes)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    kb = k_ref[0, 0].astype(jnp.float32)                  # (ps, K, hd)
-    vb = v_ref[0, 0].astype(jnp.float32)
     if fused:
-        # The boundary page holds the write position: inject the new
-        # token's K/V row and write the visited page back through the
-        # page table (rows > pos_b % ps stay bit-identical; the page
-        # is private to this slot by the CoW admission rule).
-        row = jax.lax.broadcasted_iota(jnp.int32, (kb.shape[0], 1, 1), 0)
-        hit = (jc == tb) & (row == pos_b % ps)
-        kb = jnp.where(hit, nk_ref[0].astype(jnp.float32)[None], kb)
-        vb = jnp.where(hit, nv_ref[0].astype(jnp.float32)[None], vb)
-        ck_ref[0, 0] = kb.astype(ck_ref.dtype)
-        cv_ref[0, 0] = vb.astype(cv_ref.dtype)
+        r = pos_b % ps
+        writes = [pltpu.make_async_copy(
+            new.at[0], out.at[(layer, boundary, r) + tile[1:]], wsems.at[i])
+            for i, (new, out) in enumerate(((nk_ref, ck_hbm),
+                                            (nv_ref, cv_hbm)))]
+        for w in writes:
+            w.start()
 
-    @pl.when((j >= lo) & (j <= tb))
-    def _accumulate():
-        q = (q_ref[0].astype(jnp.float32) * scale).reshape(
-            kb.shape[1], group, -1)
-        s = jnp.einsum("kgd,tkd->kgt", q, kb)             # (K, G, ps)
+    def page_copies(c, slot):
+        """(live, k copy, v copy) for each page of block ``c``."""
+        first = lo + c * ppb
+        out = []
+        for i in range(ppb):
+            page = pt_ref[pt_row + jnp.minimum(first + i, tb)]
+            out.append((first + i <= tb,
+                        pltpu.make_async_copy(k_hbm.at[(layer, page) + tile],
+                                              kbuf.at[slot, i],
+                                              sems.at[0, slot]),
+                        pltpu.make_async_copy(v_hbm.at[(layer, page) + tile],
+                                              vbuf.at[slot, i],
+                                              sems.at[1, slot])))
+        return out
+
+    def start(c, slot):
+        for live, ck, cv in page_copies(c, slot):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(c, slot):
+        for live, ck, cv in page_copies(c, slot):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
+
+    @pl.when(n_blocks > 0)
+    def _prefetch():
+        start(0, 0)
+
+    H = K * group
+    q = q_ref[0].astype(kbuf.dtype)                       # (H, hd)
+    # scores (H, T*K): row h = k*G + g, column c = t*K + k'; only
+    # k' == k is attention, the rest is masked
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, T * K), 1)
+    same_head = (col % K) == jax.lax.broadcasted_iota(
+        jnp.int32, (H, T * K), 0) // group
+    row_t = jax.lax.broadcasted_iota(jnp.int32, (T * K, 1), 0) // K
+
+    def block(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < n_blocks)
+        def _next():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        first = lo + c * ppb
+        if fused:
+            # the boundary page is in this block: attend the new row
+            @pl.when(c == n_blocks - 1)
+            def _inject():
+                kbuf[slot, tb - first, pos_b % ps] = nk_ref[0]
+                vbuf[slot, tb - first, pos_b % ps] = nv_ref[0]
+
+        kb = kbuf[slot, :, :, :, :hd].reshape(T * K, hd)
+        vb = vbuf[slot, :, :, :, :hd].reshape(T * K, hd).astype(jnp.float32)
+        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if logit_cap:
             s = logit_cap * jnp.tanh(s / logit_cap)
-        # logical position of each key row (jc == j inside the gate)
-        k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        ok = k_pos <= pos_b
+        k_pos = first * ps + col // K
+        ok = same_head & (k_pos <= pos_b)
         ok &= (win <= 0) | (k_pos > pos_b - win)
         s = jnp.where(ok, s, NEG_INF)
+        # pages past tb were not copied: zero their stale V rows
+        vb = jnp.where(first * ps + row_t <= pos_b, vb, 0.0)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=2))
-        p = jnp.exp(s - m_new[..., None])
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=2)
-        acc_scr[...] = (acc_scr[...] * corr[..., None]
-                        + jnp.einsum("kgt,tkd->kgd", p, vb))
-        m_scr[...] = m_new
+        l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
+        acc = acc * corr + jnp.dot(p, vb, preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _emit():
-        acc = acc_scr[...] / jnp.maximum(l_scr[...], 1e-37)[..., None]
-        o_ref[0] = acc.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, hd), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+    if fused:
+        for w in writes:
+            w.wait()
 
 
 def _call(q, k, v, page_table, pos, window, layer, new_k, new_v, *,
           logit_cap, fused, interpret):
     B, H, hd = q.shape
-    _, _, ps, K, _ = k.shape
+    _, P, ps, K, _ = k.shape
     nb = page_table.shape[1]
     if H % K:
         raise ValueError(f"q heads {H} not divisible by kv heads {K}")
     G = H // K
+    ppb = pages_per_block(nb, ps, K, hd, k.dtype)
+    # On the chip the pools' tiled layout pads each (K, hd) slab to whole
+    # 128-lane tiles, and Mosaic slices an HBM ref only in whole tiles:
+    # the kernel moves the padded width and computes on the first hd
+    # lanes.  The interpreter holds the logical width.
+    lanes = hd if interpret else -(-hd // 128) * 128
 
-    pt = jnp.asarray(page_table, jnp.int32)
+    pt = jnp.asarray(page_table, jnp.int32).reshape(-1)
     pos = jnp.asarray(pos, jnp.int32)
     win = jnp.asarray(window, jnp.int32).reshape(1)
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
     kernel = functools.partial(
-        _paged_kernel, ps=ps, group=G, logit_cap=float(logit_cap),
-        scale=hd ** -0.5, fused=fused)
+        _paged_kernel, ps=ps, nb=nb, ppb=ppb, trash=P - 1, group=G, hd=hd,
+        logit_cap=float(logit_cap), scale=hd ** -0.5, fused=fused)
 
-    def q_map(b, j, pt_ref, pos_ref, win_ref, layer_ref):
+    def row_map(b, *_):
         return (b, 0, 0)
 
-    def kv_map(b, j, pt_ref, pos_ref, win_ref, layer_ref):
-        lo, tb = _block_bounds(pos_ref[b], win_ref[0], ps)
-        return (layer_ref[0], pt_ref[b, jnp.clip(j, lo, tb)], 0, 0, 0)
-
-    kv_block = (1, 1, ps, K, hd)
-    in_specs = [
-        pl.BlockSpec((1, H, hd), q_map),
-        pl.BlockSpec(kv_block, kv_map),
-        pl.BlockSpec(kv_block, kv_map),
-    ]
-    out_specs = [pl.BlockSpec((1, H, hd), q_map)]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, H, hd), row_map), hbm, hbm]
+    out_specs = [pl.BlockSpec((1, H, hd), row_map)]
     out_shape = [jax.ShapeDtypeStruct((B, H, hd), q.dtype)]
     operands = [q, k, v]
     scratch = [
-        pltpu.VMEM((K, G), jnp.float32),      # m (running max, per head)
-        pltpu.VMEM((K, G), jnp.float32),      # l (running sum, per head)
-        pltpu.VMEM((K, G, hd), jnp.float32),  # acc
+        pltpu.VMEM((2, ppb, ps, K, lanes), k.dtype),   # K blocks, 2 slots
+        pltpu.VMEM((2, ppb, ps, K, lanes), v.dtype),   # V blocks
+        pltpu.SemaphoreType.DMA((2, 2)),            # (k|v, slot)
     ]
     aliases = {}
     if fused:
-        in_specs += [pl.BlockSpec((1, K, hd), q_map),
-                     pl.BlockSpec((1, K, hd), q_map)]
-        operands += [new_k, new_v]
-        out_specs += [pl.BlockSpec(kv_block, kv_map),
-                      pl.BlockSpec(kv_block, kv_map)]
+        in_specs += [pl.BlockSpec((1, K, lanes), row_map),
+                     pl.BlockSpec((1, K, lanes), row_map)]
+        pad = ((0, 0), (0, 0), (0, lanes - hd))
+        operands += [jnp.pad(new_k, pad), jnp.pad(new_v, pad)]
+        out_specs += [hbm, hbm]
         out_shape += [jax.ShapeDtypeStruct(k.shape, k.dtype),
                       jax.ShapeDtypeStruct(v.shape, v.dtype)]
-        # the whole layer stack in place: operand indices count the 4
-        # scalar-prefetch args (pt, pos, win, layer), so k/v sit at 5/6;
-        # blocks the grid never maps (other layers, unmapped pages of
-        # this one) keep their input bits.
+        scratch += [pltpu.SemaphoreType.DMA((2,))]  # the row writes
+        # the whole layer stacks in place: operand indices count the 4
+        # scalar-prefetch args (pt, pos, win, layer), so k/v sit at 5/6
         aliases = {5: 1, 6: 2}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, nb),
+        grid=(B,),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -184,6 +257,9 @@ def _call(q, k, v, page_table, pos, window, layer, new_k, new_v, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(pt, pos, win, lyr, *operands)
     return tuple(out) if fused else out[0]
@@ -194,7 +270,9 @@ def paged_decode_attention(q, k, v, page_table, pos, window=0, *,
     """Paged decode attention; the pool already holds the new KV row.
 
     q (B,H,hd); k/v one layer's pools (P,ps,K,hd); page_table (B,nb)
-    i32; pos (B,) i32 -> o (B,H,hd)."""
+    i32; pos (B,) i32 -> o (B,H,hd).  A row whose page at ``pos // ps``
+    is the pool's last page (TRASH) is idle: it reads no page and its
+    output is zero."""
     return _call(q, k[None], v[None], page_table, pos, window, 0, None,
                  None, logit_cap=logit_cap, fused=False, interpret=interpret)
 

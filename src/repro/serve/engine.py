@@ -1231,8 +1231,12 @@ class PagedEngine(Engine):
         self._upf_windows += 1
         if self.tracer is None:
             return None
+        # grid_pages: the (slots x table width) pages a kernel stepping
+        # every slot over every page would visit; the paged kernel walks
+        # the ``mapped`` ones
         return {"mapped": len(mapped), "unique": unique,
-                "unique_page_fraction": frac}
+                "unique_page_fraction": frac,
+                "grid_pages": self.slots * self.nb}
 
     def _release_slot(self, s: int) -> None:
         for p in self._slot_pages[s]:

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
-from repro.kernels import ops, ref
+from repro.kernels import ops, paged_attention, ref
 from repro.models import build_model
 from repro.serve import (EngineReference, PagedEngine, PagePool,
                          PagePoolExhausted, RadixTree,
@@ -104,47 +104,102 @@ def test_tree_match_insert_cow_boundary_coverage():
 # --- paged decode kernel vs oracle ------------------------------------------
 
 
-def _rand_paged(seed, B=3, nb=4, ps=8, K=2, G=2, hd=16, share=True):
+def _rand_paged(seed, B=3, nb=4, ps=8, K=2, G=2, hd=16, share=True,
+                pos=None, idle=()):
+    """Random pool (P = B*nb + 1 pages, the last TRASH), private page-table
+    rows (rows 1+ share row 0's first page when ``share``), queries and
+    positions.  Rows in ``idle`` are freed slots: a table row of TRASH
+    only and a stale position."""
     rng = np.random.default_rng(seed)
     P = B * nb + 1                        # + TRASH
     k = jnp.asarray(rng.normal(size=(P, ps, K, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(P, ps, K, hd)), jnp.float32)
     pt = np.arange(B * nb).reshape(B, nb).astype(np.int32)
-    if share:                             # rows 1+ share row 0's first page
+    if share:
         pt[1:, 0] = pt[0, 0]
+    pt[list(idle)] = P - 1
     q = jnp.asarray(rng.normal(size=(B, K * G, hd)), jnp.float32)
-    pos = jnp.asarray(rng.integers(0, nb * ps, size=B), jnp.int32)
-    return q, k, v, jnp.asarray(pt), pos
+    if pos is None:
+        pos = rng.integers(0, nb * ps, size=B)
+    return q, k, v, jnp.asarray(pt), jnp.asarray(pos, jnp.int32)
 
 
-@pytest.mark.parametrize("window", [0, 11])
-def test_paged_kernel_matches_oracle(window):
-    q, k, v, pt, pos = _rand_paged(0)
-    out = ops.paged_decode_attention(q, k, v, pt, pos, window)
+# Pages per DMA block follow from a page's bytes; these small pages would
+# all fit one block, so cases that need several blocks set ``ppb``.
+# Rows (nb = 7 pages of 8): pos 0; the last row of block 0 and the first
+# of block 1 at ppb 3; mid-block; the last position of the last page; an
+# idle row with a stale position.
+EDGE_POS = [0, 23, 24, 37, 55, 50]
+EDGE = dict(B=6, nb=7, pos=EDGE_POS, idle=(5,))
+ACTIVE = slice(0, 5)
+
+
+@pytest.fixture
+def ppb(request, monkeypatch):
+    if request.param is not None:
+        monkeypatch.setattr(paged_attention, "pages_per_block",
+                            lambda *a: request.param)
+    return request.param
+
+
+def _paged_call(fused, *a, **kw):
+    """The kernel itself, traced afresh (the jitted ``ops`` wrappers would
+    keep the first block size they traced)."""
+    fn = (paged_attention.paged_decode_attention_fused if fused
+          else paged_attention.paged_decode_attention)
+    return fn(*a, interpret=True, **kw)
+
+
+@pytest.mark.parametrize("ppb,window,rows", [
+    (None, 0, "random"), (None, 11, "random"), (3, 0, "edges"),
+    (3, 11, "edges"), (2, 19, "edges"), (4, 0, "edges"),
+], ids=["0", "11", "ppb3", "ppb3_window",
+        "ppb2_window_spans_blocks", "ppb4"], indirect=["ppb"])
+def test_paged_kernel_matches_oracle(ppb, window, rows):
+    """Every active row attends exactly its live keys, whatever the block
+    split; an idle row (all TRASH) walks nothing and reads zeros."""
+    if rows == "random":
+        q, k, v, pt, pos = _rand_paged(0)
+        out = ops.paged_decode_attention(q, k, v, pt, pos, window)
+        act = slice(None)
+    else:
+        q, k, v, pt, pos = _rand_paged(0, **EDGE)
+        out = _paged_call(False, q, k, v, pt, pos, window)
+        act = ACTIVE
+        np.testing.assert_array_equal(np.asarray(out[5]), 0.0)
     want = ref.paged_decode_attention_ref(q, k, v, pt, pos, window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+    np.testing.assert_allclose(np.asarray(out[act]), np.asarray(want[act]),
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("layers,layer", [(1, 0), (3, 0), (3, 2)],
-                         ids=["one_layer", "stack3_layer0", "stack3_layer2"])
-def test_paged_fused_scatter_bitwise_and_attends_new_kv(layers, layer):
+@pytest.mark.parametrize("layers,layer,ppb,rows", [
+    (1, 0, None, "random"), (3, 0, None, "random"), (3, 2, None, "random"),
+    (3, 1, 3, "edges"), (1, 0, 4, "edges"),
+], ids=["one_layer", "stack3_layer0", "stack3_layer2", "stack3_ppb3_edges",
+        "one_layer_ppb4_edges"], indirect=["ppb"])
+def test_paged_fused_scatter_bitwise_and_attends_new_kv(layers, layer, ppb,
+                                                        rows):
     """The fused kernel writes only layer ``layer``'s boundary rows of the
-    layer-stacked pools; every other layer keeps its bits."""
-    q, k, v, pt, pos = _rand_paged(1, share=False)
+    layer-stacked pools (an idle row's lands in TRASH); every other bit
+    of both stacks, other layers included, keeps its value."""
+    kw = dict(share=False) if rows == "random" else dict(EDGE, share=False)
+    q, k, v, pt, pos = _rand_paged(1, **kw)
+    B, P, ps = q.shape[0], k.shape[0], 8
     rng = np.random.default_rng(2)
-    nk = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
-    nv = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
+    nk = jnp.asarray(rng.normal(size=(B, 2, 16)), jnp.float32)
+    nv = jnp.asarray(rng.normal(size=(B, 2, 16)), jnp.float32)
     # other layers: distinct random pools, so a write to the wrong layer
     # or a stale block shows
     others = rng.normal(size=(2, layers) + k.shape).astype(np.float32)
     ks, vs = others[0], others[1]
     ks[layer], vs[layer] = np.asarray(k), np.asarray(v)
-    o, k2, v2 = ops.paged_decode_attention_fused(
-        q, jnp.asarray(ks), jnp.asarray(vs), nk, nv, pt, pos, 0, layer)
-    ps = 8
+    args = (q, jnp.asarray(ks), jnp.asarray(vs), nk, nv, pt, pos, 0, layer)
+    if rows == "random":
+        o, k2, v2 = ops.paged_decode_attention_fused(*args)
+    else:
+        o, k2, v2 = _paged_call(True, *args)
     ek, ev = ks.copy(), vs.copy()
-    for b in range(3):
+    for b in range(B):
         page = int(pt[b, int(pos[b]) // ps])
         ek[layer, page, int(pos[b]) % ps] = np.asarray(nk[b])
         ev[layer, page, int(pos[b]) % ps] = np.asarray(nv[b])
@@ -152,19 +207,48 @@ def test_paged_fused_scatter_bitwise_and_attends_new_kv(layers, layer):
     np.testing.assert_array_equal(np.asarray(v2), ev)
     want = ref.paged_decode_attention_ref(
         q, jnp.asarray(ek[layer]), jnp.asarray(ev[layer]), pt, pos)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(want),
+    act = slice(None) if rows == "random" else ACTIVE
+    np.testing.assert_allclose(np.asarray(o[act]), np.asarray(want[act]),
                                atol=2e-5, rtol=2e-5)
+    assert P - 1 not in np.asarray(pt[act])
 
 
-def test_paged_kernel_ignores_pages_beyond_pos():
-    """DMA clamping: garbage in pages past a row's depth cannot leak."""
-    q, k, v, pt, pos = _rand_paged(3, share=False)
-    pos = jnp.asarray([2, 9, 17], jnp.int32)     # well inside the table
-    base = ops.paged_decode_attention(q, k, v, pt, pos, 0)
-    k2 = k.at[np.asarray(pt)[:, 3]].set(1e9)     # poison last mapped pages
-    v2 = v.at[np.asarray(pt)[:, 3]].set(1e9)
-    poisoned = ops.paged_decode_attention(q, k2, v2, pt, pos, 0)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(poisoned))
+@pytest.mark.parametrize("fused,ppb", [(False, None), (False, 2),
+                                       (True, None), (True, 2)],
+                         ids=["plain", "plain_ppb2", "fused", "fused_ppb2"],
+                         indirect=["ppb"])
+def test_paged_kernel_ignores_pages_beyond_pos(fused, ppb):
+    """Pages past a row's depth and the TRASH page are never attended:
+    poisoning them (NaN) leaves every active row's output bit-identical,
+    also where the last block holds pages it does not copy."""
+    q, k, v, pt, pos = _rand_paged(3, B=4, share=False, idle=(3,))
+    pos = jnp.asarray([2, 9, 17, 30], jnp.int32)  # well inside the table
+    rng = np.random.default_rng(4)
+    nk = jnp.asarray(rng.normal(size=(4, 2, 16)), jnp.float32)
+    nv = jnp.asarray(rng.normal(size=(4, 2, 16)), jnp.float32)
+
+    def run(k, v):
+        if fused:
+            return _paged_call(True, q, k[None], v[None], nk, nv, pt, pos,
+                               0, 0)[0]
+        return _paged_call(False, q, k, v, pt, pos, 0)
+
+    base = run(k, v)
+    dead = np.append(np.asarray(pt)[:3, 3], k.shape[0] - 1)
+    poisoned = run(k.at[dead].set(jnp.nan), v.at[dead].set(jnp.nan))
+    np.testing.assert_array_equal(np.asarray(base[:3]),
+                                  np.asarray(poisoned[:3]))
+    assert np.isfinite(np.asarray(poisoned)).all()
+
+
+@pytest.mark.parametrize("nb,kv_heads,head_dim,want", [
+    (160, 4, 128, 16), (64, 32, 96, 2), (8, 4, 128, 8), (64, 64, 256, 1),
+], ids=["qwen2h", "phi3", "capped_by_table", "page_over_budget"])
+def test_pages_per_block_follows_page_bytes(nb, kv_heads, head_dim, want):
+    """The DMA block is as many 16-row bf16 pages as fit the K+V budget,
+    at least one and at most the table's width."""
+    assert paged_attention.pages_per_block(
+        nb, 16, kv_heads, head_dim, jnp.bfloat16) == want
 
 
 # --- fused sampling ----------------------------------------------------------

@@ -105,6 +105,7 @@ def test_paged_step_leaves_abut_and_admit_spans_the_admission(traced):
     pre = [s for s in tracer._spans if s["cat"] == "pre_window"]
     assert all(0 < s["args"]["unique_page_fraction"] <= 1
                and s["args"]["unique"] <= s["args"]["mapped"]
+               <= s["args"]["grid_pages"] == eng.slots * eng.nb
                for s in pre)
     trace = tracer.to_chrome_trace()
     validate_chrome_trace(trace)

@@ -78,12 +78,12 @@ def _dense(fused):
             *rows, ((SLOTS,), jnp.int32), ((), jnp.int32)]
 
 
-def _paged(fused, widths):
+def _paged(fused, widths, max_len=None):
     """Operands of the paged kernel: the fused variant takes the
     layer-stacked pools and a layer index, the plain one a layer's pool."""
     bf = jnp.bfloat16
-    slots, heads, kv_heads, head_dim, max_len, _ = WIDTHS[widths]
-    nb = max_len // PAGE
+    slots, heads, kv_heads, head_dim, positions, _ = WIDTHS[widths]
+    nb = (max_len or positions) // PAGE
     pool = (slots * nb + 1, PAGE, kv_heads, head_dim)
     if fused:
         pool = (LAYERS,) + pool
@@ -101,13 +101,17 @@ def test_decode_attention_compiles(one_chip, fused):
     _compile(fn, one_chip, *_dense(fused))
 
 
-@pytest.mark.parametrize("fused,widths", [
-    (False, "phi3"), (True, "phi3"), (False, "qwen2h"), (True, "qwen2h"),
-], ids=["plain", "fused", "plain-qwen2h", "fused-qwen2h"])
-def test_paged_decode_attention_compiles(one_chip, fused, widths):
+@pytest.mark.parametrize("fused,widths,max_len", [
+    (False, "phi3", None), (True, "phi3", None), (False, "qwen2h", None),
+    (True, "qwen2h", None), (False, "phi3", 1024), (True, "phi3", 1024),
+], ids=["plain", "fused", "plain-qwen2h", "fused-qwen2h", "plain-phi3-cell",
+        "fused-phi3-cell"])
+def test_paged_decode_attention_compiles(one_chip, fused, widths, max_len):
+    """Each cell's production shapes: qwen2h 48 slots x 160 pages, phi3 8
+    x 64 (its cell's 1024 positions) and 8 x 128."""
     fn = (paged_attention.paged_decode_attention_fused if fused
           else paged_attention.paged_decode_attention)
-    _compile(fn, one_chip, *_paged(fused, widths))
+    _compile(fn, one_chip, *_paged(fused, widths, max_len))
 
 
 def _while_computations(hlo):
