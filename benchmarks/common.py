@@ -1,70 +1,27 @@
-"""Shared benchmark utilities: timing, CSV row emission, BENCH records.
+"""Shared benchmark utilities: timing and CSV row emission.
 
 Timing discipline (DESIGN.md §14): JAX dispatch is asynchronous, so a
 timing loop that reads ``perf_counter`` without blocking on the outputs
-measures launch overhead, not the computation — the seed's ``timed``
-did exactly that and undercounted every warm jitted benchmark.  ``timed``
-now blocks on each iteration's outputs, and every record written through
-``append_bench_record`` is stamped ``clock: "blocking"`` so the CI
-ratchet (benchmarks/gate.py) never compares post-fix numbers against
-pre-fix history.
+measures launch overhead, not the computation.  ``timed`` blocks on each
+iteration's outputs.  The rows are CPU timings of the paper-figure
+scripts, printed beside their paper-target comparisons; they are not
+speed evidence (the chip benchmark, ``bench/``, is).
 """
 from __future__ import annotations
 
-import json
-import os
 import time
-from pathlib import Path
-from typing import Callable, List, Tuple
+from typing import Callable
 
 import jax
 
-ROWS: List[Tuple[str, float, str]] = []
-
-# Timing-discipline marker stamped into every BENCH record: "blocking"
-# means the timed loop called jax.block_until_ready before reading the
-# clock.  Records without the field predate the fix ("naive" clock) and
-# are ratcheted separately by benchmarks/gate.py.
-CLOCK = "blocking"
-
-
-def append_bench_record(path: Path, record: dict) -> None:
-    """Append ``record`` to a ``BENCH_*.json`` {latest, history} file.
-
-    The write is atomic (tmp file + ``os.replace``), so a killed bench
-    run can no longer truncate the file and destroy the history the CI
-    ratchet depends on.  If the existing file is malformed it is
-    preserved to a ``.corrupt`` sidecar instead of being clobbered, and
-    the history restarts from this record.
-    """
-    record = dict(record)
-    record.setdefault("clock", CLOCK)
-    history = []
-    if path.exists():
-        text = path.read_text()
-        try:
-            loaded = json.loads(text)
-            history = loaded.get("history", [])
-            if not isinstance(history, list):
-                raise ValueError("history is not a list")
-        except (json.JSONDecodeError, AttributeError, ValueError):
-            path.with_name(path.name + ".corrupt").write_text(text)
-            history = []
-    history.append(record)
-    payload = json.dumps({"latest": record, "history": history},
-                         indent=2) + "\n"
-    tmp = path.with_name(f"{path.name}.tmp.{os.getpid()}")
-    tmp.write_text(payload)
-    os.replace(tmp, path)
-
 
 def timed(name: str, fn: Callable, *, repeats: int = 3):
-    """Run fn, record (name, us_per_call, derived-summary-string).
+    """Run fn warm ``repeats`` times; return (last output, us per call).
 
     Blocks on each call's outputs (``jax.block_until_ready``) before
     reading the clock — without this, async dispatch returns as soon as
     the work is enqueued and warm timings collapse toward launch
-    overhead (regression-tested in tests/test_bench_gate.py).
+    overhead (regression-tested in tests/test_bench_timed.py).
     """
     jax.block_until_ready(fn())  # warmup / build caches
     t0 = time.perf_counter()
@@ -76,7 +33,6 @@ def timed(name: str, fn: Callable, *, repeats: int = 3):
 
 
 def emit(name: str, us: float, derived: str):
-    ROWS.append((name, us, derived))
     print(f"{name},{us:.1f},{derived}")
 
 

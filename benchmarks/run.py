@@ -20,13 +20,6 @@ MODULES = [
     "fig8_9_isoarea",
     "fig10_ppa",
     "fig11_13_scalability",
-    "sweep_engine",
-    "cachesim_ladder",
-    "traffic_engine",
-    "serve_engine",
-    "serve_resilience",
-    "train_engine",
-    "kernels_micro",
     "crosslayer_tpu",
 ]
 
@@ -34,10 +27,6 @@ MODULES = [
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
-    ap.add_argument("--gate", action="store_true",
-                    help="after the selected benchmarks, run the "
-                         "benchmarks.gate regression ratchet over the "
-                         "BENCH_*.json histories")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -52,10 +41,6 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             failed.append(mod_name)
-    if args.gate and not failed:
-        from benchmarks.gate import main as gate_main
-        if gate_main([]) != 0:
-            failed.append("gate")
     if failed:
         print(f"FAILED: {failed}", file=sys.stderr)
         raise SystemExit(1)

@@ -41,7 +41,7 @@ PHASES = {
     "pallas_paged": ["--attn-impl", "pallas_paged", "--shared-prefix",
                      "--page-size", "16"],
 }
-ATTN_TOL = 2e-2          # the bound benchmarks/kernels_micro.py gates
+ATTN_TOL = 2e-2          # the bf16 bound of the tests/test_kernels.py sweeps
 # kernel-phase widths: phi3-mini's heads, 2048 cache positions
 KV_HEADS, HEAD_DIM, KV_LEN, PAGE = 32, 96, 2048, 16
 # ladder: long enough that simulation, not launch, dominates the kernel,

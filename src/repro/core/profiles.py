@@ -44,8 +44,7 @@ __all__ = ["MemoryProfile", "TRAFFIC", "profile", "profile_reference",
 
 def _layer_traffic(net: Network, batch: int, training: bool, t=None):
     """Scalar float64 reference (the seed implementation) — the engine's
-    parity oracle and the per-point baseline of
-    ``benchmarks/traffic_engine.py``.  Keep in sync with
+    parity oracle (tests/test_traffic_engine.py).  Keep in sync with
     ``traffic._traffic_jit``."""
     t = t or TRAFFIC
     reads = writes = 0.0

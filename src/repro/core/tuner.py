@@ -53,8 +53,8 @@ def _tune_cached(mem: str, capacity_mb: float) -> CachePPA:
 def tune_reference(mem: str, capacity_mb: float, cal=None) -> CachePPA:
     """Legacy per-point Algorithm 1 (Python loops over ``design_grid``).
 
-    Kept as the ground truth the batched engine is tested against and as
-    the baseline for ``benchmarks/sweep_engine.py``'s speedup measurement.
+    Kept as the ground truth the batched engine is tested against
+    (tests/test_sweep_engine.py).
     """
     from repro.core.cache_model import CAL
     grid = design_grid(mem, float(capacity_mb), cal or CAL)

@@ -16,8 +16,7 @@ on the VPU, no serialized per-way branching.
 tile of one ladder rung, derives set ids / tags from the raw line trace
 and its rung's set count in-kernel, and touches only the one (1, ways)
 LRU row an access maps to (dynamic-slice read/modify/write) — O(ways)
-work per access instead of O(sets_tile x ways), which is what makes the
-whole-ladder batch beat the per-point loop (BENCH_cachesim.json).
+work per access instead of O(sets_tile x ways).
 
 Inputs: per-point takes set_ids/tags (T,) int32 precomputed from line
 addresses; the ladder engine takes raw line traces (W, T) int32 plus the

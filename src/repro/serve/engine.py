@@ -32,7 +32,7 @@ STT/SOT-MRAM tiers on the engine's REAL decode traffic — decode is the
 memory-bound regime where DeepNVM++ predicts MRAM pays off most.
 
 ``EngineReference`` keeps the seed per-tick path (per-token prefill, one
-host round-trip per tick) as the correctness oracle and benchmark baseline.
+host round-trip per tick) as the correctness oracle.
 """
 from __future__ import annotations
 
@@ -347,13 +347,11 @@ class Engine:
     def __init__(self, model: Model, params, *, slots: int, max_len: int,
                  eos_id: Optional[int] = None, seed: int = 0,
                  ticks_per_sync: int = 8, record_traffic: bool = True,
-                 prefill_attn_impl: str = "naive",
                  attn_impl: str = "xla", tracer=None,
                  sample_impl: str = "xla",
-                 charge_prefill_ticks: bool = False,
                  shed_policy: Optional[ShedPolicy] = None,
                  watchdog: Optional[WindowWatchdog] = None,
-                 fault_plan=None, health_check: bool = True):
+                 fault_plan=None):
         if "dense" not in model.serve_modes:
             raise UnsupportedFamilyError(
                 model.cfg.family, serve_families("dense"), "Engine")
@@ -373,10 +371,6 @@ class Engine:
         if self.ticks_per_sync < 1:
             raise ValueError("ticks_per_sync must be >= 1")
         self.record_traffic = record_traffic
-        # admission chunks are short (P <= max_len); the O(P^2) reference
-        # attention beats the flash-scan machinery there, and parity is on
-        # greedy argmax, not bitwise logits
-        self.prefill_attn_impl = prefill_attn_impl
         # decode-tick attention: "xla" = jnp decode_attention (full-cache
         # broadcast; the parity oracle), "pallas_decode" = blocked Pallas
         # kernel with fused in-launch KV scatter (DESIGN.md §13)
@@ -391,23 +385,18 @@ class Engine:
             raise ValueError(
                 f"sample_impl {sample_impl!r} not in {self.SAMPLE_IMPLS}")
         self.sample_impl = sample_impl
-        # opt-in tick-domain prefill accounting: each admission charges
-        # ceil(prefilled_tokens / slots) ticks BEFORE stamping the admitted
-        # requests, so tick-domain TTFT reflects prompt-processing cost
-        # (benchmarks enable it on both legs to expose prefix-sharing wins)
-        self.charge_prefill_ticks = bool(charge_prefill_ticks)
         # optional serve.telemetry.Tracer: records the spans that tile
         # each step (admission phases, prefill, decode window, host
         # drain) for chrome://tracing export (DESIGN.md §14)
         self.tracer = tracer
         # resilience layer (DESIGN.md §16): admission control, bounded
-        # window retry, per-slot output health checks, and an optional
-        # chaos FaultPlan whose on_site() hooks fire at the named sites
+        # window retry, per-slot output health checks (always on), and an
+        # optional chaos FaultPlan whose on_site() hooks fire at the named
+        # sites
         self.shed_policy = shed_policy if shed_policy is not None \
             else ShedPolicy()
         self.watchdog = watchdog if watchdog is not None else WindowWatchdog()
         self.fault_plan = fault_plan
-        self.health_check = bool(health_check)
         self._vocab = int(model.cfg.vocab_size)
         self._decode_attn_impl = (
             "pallas_decode" if attn_impl == "pallas_decode" else "chunked")
@@ -647,8 +636,10 @@ class Engine:
                 cache["enc/out"], self._banks["enc/out"].batch_axis)
             batch["enc_out"] = cache["enc/out"]
         P = tokens.shape[1]
-        logits, fresh = self.model.prefill(
-            params, batch, attn_impl=self.prefill_attn_impl)
+        # admission chunks are short (P <= max_len); the O(P^2) reference
+        # attention beats the flash-scan machinery there, and parity is on
+        # greedy argmax, not bitwise logits
+        logits, fresh = self.model.prefill(params, batch, attn_impl="naive")
         valid = admit[:, None] & (jnp.arange(P)[None, :] < lens[:, None])
         cache = {name: (self._scatter_bank(name, cache[name], fresh[name],
                                            valid)
@@ -771,13 +762,11 @@ class Engine:
                        args={"tick": self.ticks, "admitted": take,
                              "padded_len": P,
                              "uids": [r.uid for _, r in pairs]})
-        if self.charge_prefill_ticks:
-            self.ticks += -(-int(lens.sum()) // self.slots)
         bad0: Dict[int, int] = {}
         for s, r in pairs:
             self.slot_req[s] = r
             r._mark_admitted(self.ticks, now, t_deq)
-            if self.health_check and not ok0[s]:
+            if not ok0[s]:
                 bad0[s] = 0      # poisoned prefill: discard t0, requeue
                 continue
             r.output.append(int(t0[s]))
@@ -986,16 +975,15 @@ class Engine:
         # window health check: first tick per slot whose emitted token is
         # untrustworthy (non-finite logits or out-of-vocab sample)
         bad: Dict[int, int] = {}
-        if self.health_check:
-            for s in range(self.slots):
-                if self.slot_req[s] is None:
+        for s in range(self.slots):
+            if self.slot_req[s] is None:
+                continue
+            for t in range(self.ticks_per_sync):
+                if toks[t, s] < 0:
                     continue
-                for t in range(self.ticks_per_sync):
-                    if toks[t, s] < 0:
-                        continue
-                    if not oks[t, s] or toks[t, s] >= self._vocab:
-                        bad[s] = t
-                        break
+                if not oks[t, s] or toks[t, s] >= self._vocab:
+                    bad[s] = t
+                    break
         for t in range(self.ticks_per_sync):
             for s in range(self.slots):
                 r = self.slot_req[s]
@@ -1434,13 +1422,11 @@ class PagedEngine(Engine):
                       "suffix_tokens": int(suf_lens.sum()),
                       "shared_tokens": int((full_lens - suf_lens).sum()),
                       "uids": [r.uid for _, r, _ in pairs]})
-        if self.charge_prefill_ticks:
-            self.ticks += -(-int(suf_lens.sum()) // self.slots)
         bad0: Dict[int, int] = {}
         for i, (s, r, p) in enumerate(pairs):
             self.slot_req[s] = r
             r._mark_admitted(self.ticks, now, t_admit)
-            if self.health_check and not ok0[i]:
+            if not ok0[i]:
                 # poisoned prefill (a shared or recycled page carried
                 # corrupt KV): discard t0 and requeue via quarantine —
                 # the tree flush + page scrub below cleans the source
@@ -1514,10 +1500,10 @@ class PagedEngine(Engine):
 
 
 class EngineReference:
-    """The seed per-tick serving path, kept as the correctness oracle and
-    benchmark baseline for ``Engine`` (DESIGN.md §11): prompts prefill one
-    token at a time through ``decode_step``, every decode tick round-trips
-    logits to the host, and sampling/termination run in per-request python.
+    """The seed per-tick serving path, kept as the correctness oracle for
+    ``Engine`` (DESIGN.md §11): prompts prefill one token at a time
+    through ``decode_step``, every decode tick round-trips logits to the
+    host, and sampling/termination run in per-request python.
 
     Two seed bugs are fixed so this is actually an oracle:
       * per-row position vectors replace the shared ``max(slot_pos)``
@@ -1526,7 +1512,7 @@ class EngineReference:
         instead of broadcasting the prefilling request's KV into ALL rows
         (``jnp.full((slots, 1), token)`` in the seed ``_step_slot``).
     Greedy outputs are parity-enforced against ``Engine`` in
-    tests/test_serve_engine.py and benchmarks/serve_engine.py.
+    tests/test_serve_engine.py and tests/test_chaos_scenarios.py.
 
     Family support matches ``Engine`` (every ``serve_modes``-dense
     family): recurrent/ring banks get a per-row reset at admission and a

@@ -1,12 +1,12 @@
 """Serve traffic generation + arrival drivers (fixed groups and Poisson).
 
-Shared by tests/test_serve_engine.py, benchmarks/serve_engine.py, and
-launch/serve.py so "the mixed workload" (staggered arrivals, uneven
-prompt/output lengths, eos exits) means the same thing everywhere parity
-is enforced.  With correct slot isolation a request's greedy output
-depends only on its own prompt, so outputs are scheduling-independent —
-the same request set must decode identically under any arrival pattern,
-any ticks_per_sync, and under ``EngineReference``.
+Shared by the serve tests and launch/serve.py so "the mixed workload"
+(staggered arrivals, uneven prompt/output lengths, eos exits) means the
+same thing everywhere parity is enforced.  With correct slot isolation a
+request's greedy output depends only on its own prompt, so outputs are
+scheduling-independent — the same request set must decode identically
+under any arrival pattern, any ticks_per_sync, and under
+``EngineReference``.
 
 Beyond the fixed-group drivers the module is a real traffic generator
 (DESIGN.md §14): ``poisson_requests`` draws request arrival times from a

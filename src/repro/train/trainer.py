@@ -177,7 +177,7 @@ class TrainWindow:
     the drain is one ``(K,)``-stacked metrics transfer.  The per-step
     oracle (``make_train_step`` + ``data.Pipeline``) consumes the SAME
     token stream, which is what makes loss/metric trajectories directly
-    comparable (tests/test_train_engine.py; benchmarks/train_engine.py).
+    comparable (tests/test_train_engine.py).
 
     ``record_traffic=True`` lowers+compiles the window a second time and
     runs the §8 roofline HLO walker over it; per-step terms (window / K)

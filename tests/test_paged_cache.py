@@ -20,8 +20,9 @@ from repro.kernels import ops, paged_attention, ref
 from repro.models import build_model
 from repro.serve import (EngineReference, PagedEngine, PagePool,
                          PagePoolExhausted, RadixTree,
-                         Request, mixed_requests, pages_for, run_staggered,
-                         shared_prefix_requests, staggered_groups)
+                         Request, Tracer, mixed_requests, pages_for,
+                         run_staggered, shared_prefix_requests,
+                         staggered_groups)
 
 MAX_LEN = 48
 SLOTS = 3
@@ -432,28 +433,35 @@ def test_paged_engine_fused_sampling_greedy_parity(mp):
     assert got == want
 
 
-def test_charge_prefill_ticks_rewards_prefix_sharing(mp):
-    """With prefill charged to the tick clock, the paged engine's mean
-    TTFT on a shared-prefix workload beats the dense engine's by the
-    margin prefix sharing buys (the bench asserts >= 1.5x; here we pin
-    the direction and that outputs stay bitwise-identical)."""
-    from repro.serve import Engine, latency_summary
+def test_twice_the_slots_at_equal_kv_memory(mp):
+    """Capacity: a pool holding exactly the dense engine's KV rows
+    (SLOTS x MAX_LEN, the trash page included) serves twice the slots on
+    a shared-prefix workload to completion, with more than SLOTS rows in
+    flight at once, bitwise equal to the reference."""
     model, params = mp
-    reqs = shared_prefix_requests(9, seed=6, vocab=512, num_templates=2,
-                                  template_len=26, suffix_lens=(2, 6),
+    nb = MAX_LEN // PS
+    num_pages = SLOTS * nb - 1
+    assert (num_pages + 1) * PS == SLOTS * MAX_LEN
+    # template_len 30 % 8 = 6 -> every reuse CoWs a boundary page
+    reqs = shared_prefix_requests(12, seed=3, vocab=512, num_templates=2,
+                                  template_len=30, suffix_lens=(2, 6),
                                   max_new=(3, 8))
     want = _ref_outputs(mp, reqs)
-    dense = Engine(model, params, slots=SLOTS, max_len=MAX_LEN, eos_id=7,
-                   ticks_per_sync=2, record_traffic=False,
-                   charge_prefill_ticks=True)
-    rd = copy.deepcopy(reqs)
-    assert run_staggered(dense, staggered_groups(rd, SLOTS)) == want
-    paged = _paged(mp, ticks_per_sync=2, charge_prefill_ticks=True)
-    rp = copy.deepcopy(reqs)
-    assert run_staggered(paged, staggered_groups(rp, SLOTS)) == want
-    ttft_d = latency_summary(rd)["ticks"]["ttft"]["mean"]
-    ttft_p = latency_summary(rp)["ticks"]["ttft"]["mean"]
-    assert ttft_p < ttft_d
+    tracer = Tracer()
+    eng = PagedEngine(model, params, slots=2 * SLOTS, max_len=MAX_LEN,
+                      page_size=PS, num_pages=num_pages, eos_id=7,
+                      ticks_per_sync=4, record_traffic=False,
+                      attn_impl="pallas_paged", tracer=tracer)
+    # run_staggered raises unless every request finishes
+    got = run_staggered(
+        eng, staggered_groups(copy.deepcopy(reqs), 2 * SLOTS))
+    assert got == want
+    active = [c["values"]["active"] for c in tracer._counters
+              if c["name"] == "active_slots"]
+    assert max(active) > SLOTS
+    st = eng.paged_stats()
+    assert st["prefix_tokens"] > 0 and st["cow_copies"] > 0
+    eng.pool.check(eng.tree.held_refs())
 
 
 # --- serve-mode NVM verdict plumbing ----------------------------------------

@@ -256,7 +256,9 @@ def test_quarantine_retry_budget_exhaustion_fails(dense):
 def test_crash_rebuild_resumes_bitwise(dense, ref):
     """Mid-run crash: device state is lost (reset == rebuilt engine),
     every non-terminal request — including mid-slot ones with partial
-    output — is resubmitted and finishes with reference parity."""
+    output — is resubmitted and finishes with reference parity.  This is
+    the dense engine's ``engine.crash`` chaos case
+    (tests/test_chaos_scenarios.py runs it on the other engines)."""
     reqs = mixed_requests(5, seed=4, vocab=512, prompt_lens=(2, 9),
                           max_new=(6, 12))
     want = {}

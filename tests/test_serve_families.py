@@ -90,6 +90,30 @@ def test_bank_family_parity_staggered_uneven_eos(arch):
 
 
 @pytest.mark.parametrize("arch", BANK_ARCHS)
+def test_bank_family_serve_records_and_verdicts(arch):
+    """With ``record_traffic`` each slot-bank family's records are
+    family-tagged, recurrent banks carry their write-heavier read split,
+    and every record gets an NVM verdict."""
+    from repro.core.crosslayer import RECURRENT_READ_FRACTION
+    model, params = _mp(arch)
+    eng = Engine(model, params, slots=SLOTS, max_len=MAX_LEN,
+                 ticks_per_sync=4, record_traffic=True)
+    run_staggered(eng, staggered_groups(_workload(n=4), 2))
+    fam = model.cfg.family
+    recs = eng.serve_records()
+    assert {r["kind"] for r in recs} == {"decode", "prefill"}
+    for r in recs:
+        assert r["family"] == fam
+        assert r["shape"].startswith(f"serve_{fam}_")
+        assert r["roofline"]["bytes_per_device"] > 0
+        assert r.get("read_fraction") == (
+            RECURRENT_READ_FRACTION if fam in ("ssm", "hybrid") else None)
+    verdicts = eng.nvm_verdicts()
+    assert len(verdicts) == len(recs)
+    assert all(set(v.energy_ratio) == {"STT", "SOT"} for v in verdicts)
+
+
+@pytest.mark.parametrize("arch", BANK_ARCHS)
 def test_bank_family_outputs_schedule_independent(arch):
     model, params = _mp(arch)
     eng = Engine(model, params, slots=SLOTS, max_len=MAX_LEN,
