@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import pages_per_block
 from repro.models.api import Model, serve_families
 from repro.serve.paged import (PagePool, PagePoolExhausted, RadixTree,
                                pages_for)
@@ -1151,6 +1152,10 @@ class PagedEngine(Engine):
         # gather under "xla", fused Pallas kernel under "pallas_paged"
         self._decode_attn_impl = (
             "pallas_paged" if self.attn_impl == "pallas_paged" else "xla")
+        cfg = model.cfg
+        self._kernel_ppb = pages_per_block(
+            self.nb, self.page_size, cfg.num_kv_heads, cfg.head_dim,
+            cfg.dtype)
         self._cow_jit = jax.jit(
             lambda c, src, dst: {
                 k: v.at[:, dst].set(v[:, src]) for k, v in c.items()},
@@ -1205,12 +1210,14 @@ class PagedEngine(Engine):
         # unique-page fraction of this window's decode reads: row b at
         # position p reads its first ceil((p+1)/ps) mapped pages
         mapped: List[int] = []
+        blocks = 0
         for s, r in enumerate(self.slot_req):
             if r is None:
                 continue
             pos = len(r.prompt) + len(r.output) - 1
             n = pages_for(min(pos + 1, self.max_len), self.page_size)
             mapped.extend(self._pt_host[s, :n].tolist())
+            blocks += -(-n // self._kernel_ppb)
         if not mapped:
             return None
         unique = len(set(mapped))
@@ -1221,10 +1228,12 @@ class PagedEngine(Engine):
             return None
         # grid_pages: the (slots x table width) pages a kernel stepping
         # every slot over every page would visit; the paged kernel walks
-        # the ``mapped`` ones
+        # the ``mapped`` ones, in ``kernel_blocks`` DMA blocks a
+        # global-attention layer call at the window's first tick
         return {"mapped": len(mapped), "unique": unique,
                 "unique_page_fraction": frac,
-                "grid_pages": self.slots * self.nb}
+                "grid_pages": self.slots * self.nb,
+                "kernel_blocks": blocks}
 
     def _release_slot(self, s: int) -> None:
         for p in self._slot_pages[s]:

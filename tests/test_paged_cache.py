@@ -133,6 +133,12 @@ def _rand_paged(seed, B=3, nb=4, ps=8, K=2, G=2, hd=16, share=True,
 EDGE_POS = [0, 23, 24, 37, 55, 50]
 EDGE = dict(B=6, nb=7, pos=EDGE_POS, idle=(5,))
 ACTIVE = slice(0, 5)
+# The same rows at GQA group 7 over 4 kv heads (28 q heads): a page's
+# ps*K = 32 rows write in 8-row tiles of 2 tokens, so the new row shares
+# its tile with a neighbour, at both parities (even 0 and 24, odd 23, 37
+# and 55).  At 8 kv heads a token's rows are a whole tile, written alone.
+SHAPES = {"random": {}, "edges": EDGE, "gqa7_edges": dict(EDGE, K=4, G=7),
+          "kv8_edges": dict(EDGE, K=8, G=1)}
 
 
 @pytest.fixture
@@ -154,8 +160,10 @@ def _paged_call(fused, *a, **kw):
 @pytest.mark.parametrize("ppb,window,rows", [
     (None, 0, "random"), (None, 11, "random"), (3, 0, "edges"),
     (3, 11, "edges"), (2, 19, "edges"), (4, 0, "edges"),
+    (3, 0, "gqa7_edges"), (2, 19, "gqa7_edges"),
 ], ids=["0", "11", "ppb3", "ppb3_window",
-        "ppb2_window_spans_blocks", "ppb4"], indirect=["ppb"])
+        "ppb2_window_spans_blocks", "ppb4", "gqa7_ppb3",
+        "gqa7_ppb2_window"], indirect=["ppb"])
 def test_paged_kernel_matches_oracle(ppb, window, rows):
     """Every active row attends exactly its live keys, whatever the block
     split; an idle row (all TRASH) walks nothing and reads zeros."""
@@ -164,7 +172,7 @@ def test_paged_kernel_matches_oracle(ppb, window, rows):
         out = ops.paged_decode_attention(q, k, v, pt, pos, window)
         act = slice(None)
     else:
-        q, k, v, pt, pos = _rand_paged(0, **EDGE)
+        q, k, v, pt, pos = _rand_paged(0, **SHAPES[rows])
         out = _paged_call(False, q, k, v, pt, pos, window)
         act = ACTIVE
         np.testing.assert_array_equal(np.asarray(out[5]), 0.0)
@@ -173,28 +181,33 @@ def test_paged_kernel_matches_oracle(ppb, window, rows):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("layers,layer,ppb,rows", [
-    (1, 0, None, "random"), (3, 0, None, "random"), (3, 2, None, "random"),
-    (3, 1, 3, "edges"), (1, 0, 4, "edges"),
+@pytest.mark.parametrize("layers,layer,ppb,rows,window", [
+    (1, 0, None, "random", 0), (3, 0, None, "random", 0),
+    (3, 2, None, "random", 0), (3, 1, 3, "edges", 0), (1, 0, 4, "edges", 0),
+    (3, 1, 3, "gqa7_edges", 0), (1, 0, 2, "gqa7_edges", 19),
+    (3, 2, 3, "kv8_edges", 0),
 ], ids=["one_layer", "stack3_layer0", "stack3_layer2", "stack3_ppb3_edges",
-        "one_layer_ppb4_edges"], indirect=["ppb"])
+        "one_layer_ppb4_edges", "stack3_ppb3_gqa7_edges",
+        "one_layer_ppb2_gqa7_window", "stack3_ppb3_kv8_edges"],
+    indirect=["ppb"])
 def test_paged_fused_scatter_bitwise_and_attends_new_kv(layers, layer, ppb,
-                                                        rows):
+                                                        rows, window):
     """The fused kernel writes only layer ``layer``'s boundary rows of the
-    layer-stacked pools (an idle row's lands in TRASH); every other bit
-    of both stacks, other layers included, keeps its value."""
-    kw = dict(share=False) if rows == "random" else dict(EDGE, share=False)
-    q, k, v, pt, pos = _rand_paged(1, **kw)
-    B, P, ps = q.shape[0], k.shape[0], 8
+    layer-stacked pools (an idle row writes nothing, TRASH included);
+    every other bit of both stacks, other layers included, keeps its
+    value."""
+    q, k, v, pt, pos = _rand_paged(1, **SHAPES[rows], share=False)
+    B, (P, ps, K, hd) = q.shape[0], k.shape
     rng = np.random.default_rng(2)
-    nk = jnp.asarray(rng.normal(size=(B, 2, 16)), jnp.float32)
-    nv = jnp.asarray(rng.normal(size=(B, 2, 16)), jnp.float32)
+    nk = jnp.asarray(rng.normal(size=(B, K, hd)), jnp.float32)
+    nv = jnp.asarray(rng.normal(size=(B, K, hd)), jnp.float32)
     # other layers: distinct random pools, so a write to the wrong layer
     # or a stale block shows
     others = rng.normal(size=(2, layers) + k.shape).astype(np.float32)
     ks, vs = others[0], others[1]
     ks[layer], vs[layer] = np.asarray(k), np.asarray(v)
-    args = (q, jnp.asarray(ks), jnp.asarray(vs), nk, nv, pt, pos, 0, layer)
+    args = (q, jnp.asarray(ks), jnp.asarray(vs), nk, nv, pt, pos, window,
+            layer)
     if rows == "random":
         o, k2, v2 = ops.paged_decode_attention_fused(*args)
     else:
@@ -202,12 +215,14 @@ def test_paged_fused_scatter_bitwise_and_attends_new_kv(layers, layer, ppb,
     ek, ev = ks.copy(), vs.copy()
     for b in range(B):
         page = int(pt[b, int(pos[b]) // ps])
+        if page == P - 1:                 # an idle row writes nothing
+            continue
         ek[layer, page, int(pos[b]) % ps] = np.asarray(nk[b])
         ev[layer, page, int(pos[b]) % ps] = np.asarray(nv[b])
     np.testing.assert_array_equal(np.asarray(k2), ek)
     np.testing.assert_array_equal(np.asarray(v2), ev)
     want = ref.paged_decode_attention_ref(
-        q, jnp.asarray(ek[layer]), jnp.asarray(ev[layer]), pt, pos)
+        q, jnp.asarray(ek[layer]), jnp.asarray(ev[layer]), pt, pos, window)
     act = slice(None) if rows == "random" else ACTIVE
     np.testing.assert_allclose(np.asarray(o[act]), np.asarray(want[act]),
                                atol=2e-5, rtol=2e-5)

@@ -107,6 +107,12 @@ def test_paged_step_leaves_abut_and_admit_spans_the_admission(traced):
                and s["args"]["unique"] <= s["args"]["mapped"]
                <= s["args"]["grid_pages"] == eng.slots * eng.nb
                for s in pre)
+    # these small pages all fit one DMA block: one block a live row
+    assert eng._kernel_ppb == eng.nb
+    windows = [s for s in tracer._spans if s["cat"] == "decode"]
+    assert [s["args"]["kernel_blocks"] for s in sorted(
+        pre, key=lambda s: s["t0"])] == [
+        s["args"]["active"] for s in sorted(windows, key=lambda s: s["t0"])]
     trace = tracer.to_chrome_trace()
     validate_chrome_trace(trace)
     assert {e["ph"] for e in trace["traceEvents"]} == {"X", "C", "M"}

@@ -64,10 +64,11 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache_was_on)
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, donate=()):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _dense(fused):
@@ -108,10 +109,24 @@ def test_decode_attention_compiles(one_chip, fused):
         "fused-phi3-cell"])
 def test_paged_decode_attention_compiles(one_chip, fused, widths, max_len):
     """Each cell's production shapes: qwen2h 48 slots x 160 pages, phi3 8
-    x 64 (its cell's 1024 positions) and 8 x 128."""
+    x 64 (its cell's 1024 positions) and 8 x 128.  At qwen2h's widths the
+    kernel's page-row view of the pools, (ps * kv heads, head width) rows
+    a page, is a bitcast: the program copies no pool-shaped array (the
+    fused variant's stacks donated, as the decode scan hands them over).
+    phi3's pools are not held to this: their entry layout puts the page
+    axis minor, so the program copies them in and out around the kernel."""
     fn = (paged_attention.paged_decode_attention_fused if fused
           else paged_attention.paged_decode_attention)
-    _compile(fn, one_chip, *_paged(fused, widths, max_len))
+    shapes = _paged(fused, widths, max_len)
+    text = _compile(fn, one_chip, *shapes, donate=(1, 2) if fused else ())
+    if widths == "qwen2h":
+        pages, _, kv_heads, head_dim = shapes[1][0][-4:]
+        pool = re.compile(
+            rf"= bf16\[(\d+,)?{pages},({PAGE},{kv_heads}|{PAGE * kv_heads}),"
+            rf"{head_dim}\]\S* (copy|copy-start|copy-done)\(")
+        copies = [line.strip()[:120] for line in text.splitlines()
+                  if pool.search(line)]
+        assert not copies, copies
 
 
 def _while_computations(hlo):
